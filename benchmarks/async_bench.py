@@ -316,7 +316,7 @@ def main(argv=None) -> int:
     ap.add_argument("--json", default="",
                     help="also write rows as machine-readable JSON to PATH")
     args = ap.parse_args(argv)
-    from benchmarks.common import enable_compile_cache
+    from repro.core.compile_cache import enable_compile_cache
     enable_compile_cache()
     parts = ((1.0, 0.5) if args.participations is None
              else tuple(args.participations))

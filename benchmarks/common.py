@@ -9,7 +9,6 @@ prints them as CSV.  ``us_per_call`` is wall-time per communication round.
 from __future__ import annotations
 
 import json
-import os
 import platform
 import time
 
@@ -51,25 +50,6 @@ def fedpart_schedule(num_groups, quick=True, cycles=1, rl=1, warmup=2,
     return FedPartSchedule(num_groups=num_groups, warmup_rounds=warmup,
                            rounds_per_layer=rl, cycles=cycles,
                            bridge_rounds=bridge, order=order, seed=seed)
-
-
-def enable_compile_cache() -> None:
-    """Point jax at the repo's persistent XLA compile cache (the same
-    ``.jax_cache/`` family tests/conftest.py uses; ``REPRO_BENCH_CACHE``
-    overrides the path, empty disables).  Cold bench runs are dominated by
-    XLA compiles — one warm run per machine/jax version turns every later
-    run into replays, which is what makes the CI bench-regression lane's
-    numbers about the *code* instead of the compiler."""
-    cache = os.environ.get(
-        "REPRO_BENCH_CACHE",
-        os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-                     ".jax_cache"))
-    if not cache:
-        return
-    import jax
-
-    jax.config.update("jax_compilation_cache_dir", os.path.abspath(cache))
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.2)
 
 
 def write_json_rows(path: str, rows: list[dict], **meta) -> None:

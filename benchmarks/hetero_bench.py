@@ -193,7 +193,8 @@ def main(argv=None) -> int:
                     help="write rows as machine-readable JSON (BENCH_*.json)")
     args = ap.parse_args(argv)
 
-    from benchmarks.common import enable_compile_cache, write_json_rows
+    from benchmarks.common import write_json_rows
+    from repro.core.compile_cache import enable_compile_cache
     enable_compile_cache()
     rows = bench(clients=args.clients,
                  samples_per_client=args.samples_per_client,

@@ -183,7 +183,7 @@ def main(argv=None) -> int:
     ap.add_argument("--json", default="",
                     help="also write rows as machine-readable JSON to PATH")
     args = ap.parse_args(argv)
-    from benchmarks.common import enable_compile_cache
+    from repro.core.compile_cache import enable_compile_cache
     enable_compile_cache()
     rows = run(quick=not args.full, reps=args.reps)
     print("name,us_per_call,derived")

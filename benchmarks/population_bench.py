@@ -169,7 +169,7 @@ def main(argv=None) -> int:
     ap.add_argument("--json", default="",
                     help="also write rows as machine-readable JSON to PATH")
     args = ap.parse_args(argv)
-    from benchmarks.common import enable_compile_cache
+    from repro.core.compile_cache import enable_compile_cache
     enable_compile_cache()
     rows = bench(population_small=args.population_small,
                  population_large=args.population,

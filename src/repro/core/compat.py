@@ -1,49 +1,22 @@
-"""jax API compat shims shared across the repo.
-
-jax >= 0.5 promotes ``shard_map`` to ``jax.shard_map``; the replication-check
-kwarg was also renamed (``check_rep`` -> ``check_vma``) on its own schedule.
-Resolve both the symbol and the kwarg by inspection, not version guesswork,
-in exactly one place — ``models/moe_ep.py`` and ``fl/batched.py`` both build
-on this.
-"""
+"""The repo's one spelling of jax's ``shard_map`` and abstract-mesh APIs
+(jax 0.9).  ``models/moe_ep.py`` and ``fl/batched.py`` both build on this."""
 
 from __future__ import annotations
 
-import inspect as _inspect
-
 import jax
 
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:  # pragma: no cover - depends on installed jax
-    from jax.experimental.shard_map import shard_map
+shard_map = jax.shard_map
 
-# Splat into every shard_map call to disable the replication check under
-# whichever name this jax spells it.
-SHARD_MAP_NO_CHECK_KW = {
-    ("check_vma" if "check_vma" in _inspect.signature(shard_map).parameters
-     else "check_rep"): False
-}
+# Splat into every shard_map call to disable the replication check.
+SHARD_MAP_NO_CHECK_KW = {"check_vma": False}
 
 
 def abstract_client_mesh(width: int, axis: str = "clients"):
-    """``jax.sharding.AbstractMesh`` with one ``width``-sized axis, or ``None``
-    when this jax cannot build one.
+    """``jax.sharding.AbstractMesh`` with one ``width``-sized axis.
 
     An abstract mesh lets one traced ``shard_map`` program serve every
-    concrete mesh of the same shape — the submesh bindings in ``fl/batched.py``
-    use it to share a single trace across equal-width submeshes (the concrete
-    devices come in through the inputs' ``NamedSharding``).  The constructor
-    signature has moved across jax releases, so resolve it by trying, not by
-    version guesswork; callers fall back to per-submesh concrete-mesh traces
-    on ``None``.
-    """
-    am = getattr(jax.sharding, "AbstractMesh", None)
-    if am is None:  # pragma: no cover - depends on installed jax
-        return None
-    for args in (((axis, int(width)),),), ((int(width),), (axis,)):
-        try:
-            return am(*args)
-        except TypeError:  # pragma: no cover - depends on installed jax
-            continue
-    return None  # pragma: no cover - depends on installed jax
+    concrete mesh of the same shape — the submesh bindings in
+    ``fl/batched.py`` use it to share a single trace across equal-width
+    submeshes (the concrete devices come in through the inputs'
+    ``NamedSharding``)."""
+    return jax.sharding.AbstractMesh((int(width),), (axis,))

@@ -103,6 +103,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core import aggregation, compress, masking
 from repro.core.compat import SHARD_MAP_NO_CHECK_KW as _SHARD_MAP_KW
+from repro.core.compat import abstract_client_mesh
 from repro.core.compat import shard_map as _shard_map
 from repro.core.partition import Partition
 from repro.core.schedule import FULL_NETWORK, RoundSpec, round_base_mask
@@ -981,7 +982,6 @@ class ShardMapEngine(_BatchedEngineBase):
         from repro.launch.mesh import make_client_mesh
 
         self.mesh = make_client_mesh(self.devices)
-        self._abs_meshes: dict[int, Any] = {}
 
     @property
     def num_devices(self) -> int:
@@ -1198,14 +1198,6 @@ class ShardMapEngine(_BatchedEngineBase):
     def _cohort_pad_for(self, submesh) -> int:
         return submesh.width if submesh is not None else self.num_devices
 
-    def _abstract_mesh(self, width: int):
-        """Cached AbstractMesh of ``width`` (None when this jax can't)."""
-        if width not in self._abs_meshes:
-            from repro.core.compat import abstract_client_mesh
-
-            self._abs_meshes[width] = abstract_client_mesh(width, CLIENT_AXIS)
-        return self._abs_meshes[width]
-
     def _cohort_fn(self, group: int, stacked_prev: bool, submesh=None) -> Callable:
         """Plain (no-psum) shard_map'd local round for async cohorts: each
         device vmaps its client shard and the stacked locals leave the mesh
@@ -1217,20 +1209,12 @@ class ShardMapEngine(_BatchedEngineBase):
         over an *AbstractMesh* of the submesh's width and cached per width —
         the concrete devices arrive through the inputs' ``NamedSharding``
         (``_place_cohort_args``), so every equal-width submesh replays the
-        same trace.  When this jax can't trace abstractly, fall back to one
-        concrete-mesh trace per device set (the persistent XLA cache still
-        dedups the identical HLO)."""
+        same trace."""
         if submesh is None:
             key, mesh = (group, stacked_prev), self.mesh
         else:
-            am = self._abstract_mesh(submesh.width)
-            if am is not None:
-                key, mesh = (group, stacked_prev, submesh.width), am
-            else:  # pragma: no cover - depends on installed jax
-                key = (group, stacked_prev,
-                       tuple(getattr(d, "id", i)
-                             for i, d in enumerate(submesh.devices)))
-                mesh = submesh.mesh
+            key = (group, stacked_prev, submesh.width)
+            mesh = abstract_client_mesh(submesh.width, CLIENT_AXIS)
         if key in self._cohort_fns:
             return self._cohort_fns[key]
 
@@ -1257,19 +1241,13 @@ class ShardMapEngine(_BatchedEngineBase):
     def _plan_cohort_fn(self, stacked_prev: bool, submesh=None) -> Callable:
         """Plan-round cohort program: ``_cohort_fn``'s no-psum contract with
         the per-client group bitmask riding the client axis as a sixth
-        sharded input.  Same trace-sharing story: AbstractMesh per width
-        when available, concrete mesh otherwise."""
+        sharded input.  Same trace-sharing story: one AbstractMesh per
+        width."""
         if submesh is None:
             key, mesh = ("plan", stacked_prev), self.mesh
         else:
-            am = self._abstract_mesh(submesh.width)
-            if am is not None:
-                key, mesh = ("plan", stacked_prev, submesh.width), am
-            else:  # pragma: no cover - depends on installed jax
-                key = ("plan", stacked_prev,
-                       tuple(getattr(d, "id", i)
-                             for i, d in enumerate(submesh.devices)))
-                mesh = submesh.mesh
+            key = ("plan", stacked_prev, submesh.width)
+            mesh = abstract_client_mesh(submesh.width, CLIENT_AXIS)
         if key in self._cohort_fns:
             return self._cohort_fns[key]
 
@@ -1296,8 +1274,8 @@ class ShardMapEngine(_BatchedEngineBase):
 
     def _place_cohort_args(self, args: tuple, submesh, *,
                            stacked_prev: bool) -> tuple:
-        if submesh is None or self._abstract_mesh(submesh.width) is None:
-            # concrete-mesh traces shard host arrays themselves
+        if submesh is None:
+            # the engine's concrete-mesh programs shard host arrays themselves
             return args
         from jax.sharding import NamedSharding
 

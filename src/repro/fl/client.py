@@ -20,6 +20,7 @@ from repro.core import aggregation, masking
 from repro.core.partition import Partition
 from repro.fl.algorithms import AlgoConfig, augment_loss
 from repro.fl.tasks import TaskAdapter
+from repro.kernels import default_interpret
 from repro.kernels.masked_adam import ops as madam_ops
 from repro.kernels.masked_adam.kernel import masked_adam_kernel
 from repro.optim.adam import AdamConfig, AdamState, adam_init, adam_update
@@ -129,7 +130,7 @@ class LocalTrainer:
         np_, nm, nv = masked_adam_kernel(
             pp, pg, opt_state.m, opt_state.v, jnp.asarray(block_mask),
             scalars, b1=self.adam.b1, b2=self.adam.b2, block_rows=block_rows,
-            interpret=madam_ops.default_interpret(),
+            interpret=default_interpret(),
         )
         return madam_ops.unpack(np_, meta), AdamState(step_i, nm, nv)
 

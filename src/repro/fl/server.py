@@ -59,6 +59,7 @@ exactly as before.
 from __future__ import annotations
 
 import dataclasses
+import time
 from typing import Any, Sequence
 
 import jax
@@ -267,6 +268,7 @@ def run_federated(
     population = as_population(clients_data)
     n_clients = population.num_clients
     for spec in rounds:
+        t_round = time.perf_counter()
         n_pick = resolve_cohort_size(n_clients, run_cfg.sample_fraction,
                                      run_cfg.cohort_size)
         picked = sample_without_replacement(rng, n_clients, n_pick)
@@ -302,6 +304,11 @@ def run_federated(
         if spec.index % run_cfg.eval_every == 0 or spec.index == len(rounds) - 1:
             acc = float(eval_fn(params, eval_x[: run_cfg.eval_batch], eval_y[: run_cfg.eval_batch]))
             entry["acc"] = acc
+        # Host wall clock, compiles included.  The losses (and the eval, when
+        # the round has one) are read back to the host, so the round's device
+        # work has finished by now; a round without an eval may leave its
+        # aggregation to be waited on by the next round.
+        entry["seconds"] = time.perf_counter() - t_round
         history.append(entry)
         if verbose:
             print(f"round {spec.index:3d} [{spec.phase}:{spec.group:3d}] "

@@ -2,7 +2,7 @@
 
 Handles layout (the model uses (B, S, H, D); the kernel wants (B, H, S, D)),
 head-dim padding to the 128-lane MXU width, ragged tails via sequence
-padding, and the CPU fallback (interpret mode).
+padding, and interpret mode off-TPU (``repro.kernels.default_interpret``).
 """
 
 from __future__ import annotations
@@ -12,6 +12,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import default_interpret
 from repro.kernels.flash_attention.kernel import flash_attention_kernel
 from repro.kernels.flash_attention.ref import attention_ref
 
@@ -36,10 +37,12 @@ def flash_attention_bhsd(
     *,
     causal: bool = True,
     window: int = 0,
-    interpret: bool = True,
+    interpret: bool | None = None,
     bq: int = 128,
     bk: int = 128,
 ) -> jax.Array:
+    if interpret is None:
+        interpret = default_interpret()
     sq0, skv0, d0 = q.shape[2], k.shape[2], q.shape[3]
     # MXU alignment: pad head dim to 128 lanes, seq to block multiples.
     q, _ = _pad_to(q, 3, 128)
@@ -68,7 +71,7 @@ def flash_attention(
     mask=None,               # accepted for API parity; causal masks only
     causal: bool = True,
     window: int = 0,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     qt = jnp.swapaxes(q, 1, 2)
     kt = jnp.swapaxes(k, 1, 2)

@@ -6,7 +6,6 @@ from repro.kernels.masked_adam.ops import (PackMeta,  # noqa: F401
                                            block_group_ids,
                                            block_mask_for_group,
                                            block_masks_for_plan,
-                                           default_interpret,
                                            fused_masked_adam, pack,
                                            pack_stacked, plan_block_mask,
                                            unpack, unpack_stacked)

@@ -12,8 +12,15 @@ through — on TPU the copy is also elided by aliasing the input and output
 buffers, so frozen bytes are never touched.
 
 Layout: parameters are packed to (rows, 128) lanes; the grid walks row-blocks
-of (block_rows, 128); the per-block mask and the Adam bias corrections arrive
-as scalar-prefetch-style side inputs.
+of (block_rows, 128).  The whole per-block mask is a scalar-prefetch operand
+(it sits in SMEM before the grid starts and each step reads its own bit by
+``program_id``).  A (1,) SMEM block per grid step is not an option: Mosaic
+refuses rank-1 blocks that are neither the whole array nor a multiple of 128.
+The Adam scalars [lr, bc1, bc2, eps] arrive as one (8, 128) VMEM tile, row i
+holding scalar i in every lane.  They differ per client (padded steps leave
+a client's step count behind), so under the engines' vmap they gain a client
+axis, and a (clients, 8, 128) array still tiles where a (clients, 4) SMEM
+array would not.
 
 NOTE (DESIGN.md §6): in the production FedPart path the *partitioned* update
 never materialises frozen tensors at all; this kernel serves the Eq. 1 masked
@@ -33,17 +40,19 @@ LANES = 128
 
 
 def _adam_kernel(
-    mask_ref,                     # (1,) int32 — this block's S bit
-    sc_ref,                       # (4,) f32 — [lr, bc1, bc2, eps]
+    mask_ref,                     # (num_blocks,) int32 — every block's S bit
+    sc_ref,                       # (8, 128) f32 — rows [lr, bc1, bc2, eps, 0..]
     p_ref, g_ref, m_ref, v_ref,   # (BR, 128) blocks
     p_out, m_out, v_out,
     *,
     b1: float,
     b2: float,
 ):
-    @pl.when(mask_ref[0] != 0)
+    trained = mask_ref[pl.program_id(0)] != 0
+
+    @pl.when(trained)
     def _update():
-        lr, bc1, bc2, eps = sc_ref[0], sc_ref[1], sc_ref[2], sc_ref[3]
+        lr, bc1, bc2, eps = (sc_ref[i:i + 1, :] for i in range(4))  # (1, 128)
         g = g_ref[...].astype(jnp.float32)
         m_new = b1 * m_ref[...] + (1.0 - b1) * g
         v_new = b2 * v_ref[...] + (1.0 - b2) * g * g
@@ -54,7 +63,7 @@ def _adam_kernel(
         m_out[...] = m_new
         v_out[...] = v_new
 
-    @pl.when(mask_ref[0] == 0)
+    @pl.when(jnp.logical_not(trained))
     def _copy():
         # With input/output aliasing this is elided on TPU; kept for the
         # interpret-mode semantics.
@@ -82,26 +91,23 @@ def masked_adam_kernel(
     assert block_mask.shape == (nb,), (block_mask.shape, nb)
 
     kernel = functools.partial(_adam_kernel, b1=b1, b2=b2)
+    sc_tile = jnp.zeros((8, LANES), jnp.float32).at[:4].set(
+        scalars.astype(jnp.float32)[:, None])
 
-    def blk(i):
+    def blk(i, mask_ref):
         return (i, 0)
 
+    tile = pl.BlockSpec((block_rows, LANES), blk)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(nb,),
+        in_specs=[pl.BlockSpec((8, LANES), lambda i, mask_ref: (0, 0)),
+                  tile, tile, tile, tile],
+        out_specs=[tile, tile, tile],
+    )
     return pl.pallas_call(
         kernel,
-        grid=(nb,),
-        in_specs=[
-            pl.BlockSpec((1,), lambda i: (i,), memory_space=pltpu.SMEM),
-            pl.BlockSpec((4,), lambda i: (0,), memory_space=pltpu.SMEM),
-            pl.BlockSpec((block_rows, LANES), blk),
-            pl.BlockSpec((block_rows, LANES), blk),
-            pl.BlockSpec((block_rows, LANES), blk),
-            pl.BlockSpec((block_rows, LANES), blk),
-        ],
-        out_specs=[
-            pl.BlockSpec((block_rows, LANES), blk),
-            pl.BlockSpec((block_rows, LANES), blk),
-            pl.BlockSpec((block_rows, LANES), blk),
-        ],
+        grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct(p.shape, p.dtype),
             jax.ShapeDtypeStruct(m.shape, jnp.float32),
@@ -109,7 +115,7 @@ def masked_adam_kernel(
         ],
         input_output_aliases={2: 0, 4: 1, 5: 2},
         interpret=interpret,
-    )(block_mask, scalars, p, g, m, v)
+    )(block_mask, sc_tile, p, g, m, v)
 
 
 def masked_adam_stacked(

@@ -32,6 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.partition import Partition, path_str, tree_paths
+from repro.kernels import default_interpret
 from repro.kernels.masked_adam.kernel import LANES, masked_adam_kernel
 
 PyTree = Any
@@ -252,12 +253,6 @@ def plan_block_mask(gids: np.ndarray, gmask: jax.Array) -> jax.Array:
     return jnp.where(jnp.asarray(gids >= 0), bits, False).astype(jnp.int32)
 
 
-def default_interpret() -> bool:
-    """Run the kernel in Pallas interpret mode off-TPU (CPU/GPU testing);
-    compiled Mosaic on TPU."""
-    return jax.default_backend() != "tpu"
-
-
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret", "b1", "b2"))
 def _run(packed_p, packed_g, packed_m, packed_v, block_mask, scalars,
          block_rows, interpret, b1, b2):
@@ -290,9 +285,11 @@ def fused_masked_adam(
     b2: float = 0.999,
     eps: float = 1e-8,
     block_rows: int = 8,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> tuple[PyTree, PyTree, PyTree]:
     """Fused Eq.-1 Adam over a whole pytree.  Returns (params, m, v)."""
+    if interpret is None:
+        interpret = default_interpret()
     pp, meta = pack(params, block_rows)
     pg, _ = pack(grads, block_rows)
     pm, meta_m = pack(m, block_rows)

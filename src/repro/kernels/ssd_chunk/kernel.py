@@ -13,10 +13,14 @@ Per chunk of length Q the kernel does three MXU matmuls:
     y     += (q ⊙ exp(cum)) @ S_prev                 (Q,N)·(N,P)
     S_new  = a_tot·S_prev + (k ⊙ exp(tot−cum))ᵀ @ v  (N,Q)·(Q,P)
 
-BlockSpecs tile q/k as (1,1,Q,N), v/y as (1,1,Q,P), log_a as (1,1,Q) — all
-VMEM; N, P, Q should be multiples of the 128-lane MXU width for peak
-utilisation (the wrapper pads).  The decay matrices are built in-register
-from the cumulative log-decay (exp of differences; ≤ 1, numerically safe).
+BlockSpecs tile q/k as (1,1,Q,N), v/y as (1,1,Q,P), log_a as (1,1,Q,1) — all
+VMEM.  log_a carries a trailing unit axis so that its block's last two dims
+are (Q, full); a (1,1,Q) block over (B,H,S) is refused by Mosaic.  N, P, Q
+should be multiples of the 128-lane MXU width for peak utilisation (the
+wrapper pads).  The decay matrices are built in-register from the cumulative
+log-decay (exp of differences; ≤ 1, numerically safe); the cumulative sums
+are masked (Q, Q) reductions, so the column of decays never needs a
+transpose.
 """
 
 from __future__ import annotations
@@ -46,30 +50,34 @@ def _ssd_kernel(
     q = q_ref[0, 0].astype(jnp.float32)            # (Q, N)
     k = k_ref[0, 0].astype(jnp.float32)            # (Q, N)
     v = v_ref[0, 0].astype(jnp.float32)            # (Q, P)
-    la = la_ref[0, 0].astype(jnp.float32)          # (Q,)
+    la = la_ref[0, 0].astype(jnp.float32)          # (Q, 1)
 
-    cum = jnp.cumsum(la)                           # inclusive
-    total = cum[-1]
+    # Inclusive cumulative log-decay as a row and as a column:
+    # cum_row[j] = sum_{i<=j} la_i (sublane reduction), then its diagonal
+    # read back out along lanes gives cum_col[i] = cum_row[i].
+    row = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    causal = row >= col
+    cum_row = jnp.sum(jnp.where(row <= col, la, 0.0), axis=0, keepdims=True)
+    cum_col = jnp.sum(jnp.where(row == col, cum_row, 0.0), axis=1,
+                      keepdims=True)                               # (Q, 1)
+    total = jnp.sum(la, axis=0, keepdims=True)                     # (1, 1)
 
     # Intra-chunk: scores[i,j] = (q_i·k_j)·exp(cum_i − cum_j) for i >= j.
     qk = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)   # (Q,Q)
-    diff = cum[:, None] - cum[None, :]
-    causal = (
-        jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-        >= jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    )
+    diff = cum_col - cum_row
     w = jnp.where(causal, qk * jnp.exp(diff), 0.0)
     y = jax.lax.dot_general(w, v, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)    # (Q,P)
 
     # Inter-chunk: y += (q ⊙ exp(cum)) @ S_prev
-    q_dec = q * jnp.exp(cum)[:, None]
+    q_dec = q * jnp.exp(cum_col)
     y = y + jax.lax.dot_general(q_dec, state_ref[...], (((1,), (0,)), ((), ())),
                                 preferred_element_type=jnp.float32)
 
     # State update: S = exp(total)·S + (k ⊙ exp(total−cum))ᵀ @ v
-    k_dec = k * jnp.exp(total - cum)[:, None]
+    k_dec = k * jnp.exp(total - cum_col)
     s_chunk = jax.lax.dot_general(k_dec, v, (((0,), (0,)), ((), ())),
                                   preferred_element_type=jnp.float32)  # (N,P)
     state_ref[...] = jnp.exp(total) * state_ref[...] + s_chunk
@@ -85,7 +93,7 @@ def ssd_chunk_kernel(
     q: jax.Array,        # (B, H, S, N)
     k: jax.Array,        # (B, H, S, N)
     v: jax.Array,        # (B, H, S, P)
-    log_a: jax.Array,    # (B, H, S)
+    log_a: jax.Array,    # (B, H, S, 1)
     *,
     chunk: int = 128,
     interpret: bool = False,
@@ -101,7 +109,7 @@ def ssd_chunk_kernel(
     kernel = functools.partial(_ssd_kernel, chunk=chunk)
     blk_n = pl.BlockSpec((1, 1, chunk, n), lambda bi, hi, ci: (bi, hi, ci, 0))
     blk_p = pl.BlockSpec((1, 1, chunk, p), lambda bi, hi, ci: (bi, hi, ci, 0))
-    blk_a = pl.BlockSpec((1, 1, chunk), lambda bi, hi, ci: (bi, hi, ci))
+    blk_a = pl.BlockSpec((1, 1, chunk, 1), lambda bi, hi, ci: (bi, hi, ci, 0))
     y, s_out = pl.pallas_call(
         kernel,
         grid=grid,

@@ -8,6 +8,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import default_interpret
 from repro.kernels.ssd_chunk.kernel import ssd_chunk_kernel
 from repro.kernels.ssd_chunk.ref import ssd_ref
 
@@ -30,14 +31,16 @@ def ssd_scan(
     log_a: jax.Array,    # (B, S, H)
     *,
     chunk: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Returns (y: (B,S,H,P), final_state: (B,H,N,P))."""
+    if interpret is None:
+        interpret = default_interpret()
     n0, p0 = q.shape[-1], v.shape[-1]
     qt = _pad_last(jnp.swapaxes(q, 1, 2), 128)
     kt = _pad_last(jnp.swapaxes(k, 1, 2), 128)
     vt = _pad_last(jnp.swapaxes(v, 1, 2), 128)
-    la = jnp.swapaxes(log_a, 1, 2)                 # (B,H,S)
+    la = jnp.swapaxes(log_a, 1, 2)[..., None]      # (B,H,S,1)
     y, state = ssd_chunk_kernel(qt, kt, vt, la, chunk=chunk, interpret=interpret)
     return jnp.swapaxes(y, 1, 2)[..., :p0], state[:, :, :n0, :p0]
 

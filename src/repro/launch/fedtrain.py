@@ -108,6 +108,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config
+from repro.core.compile_cache import enable_compile_cache
 from repro.core.schedule import FULL_NETWORK, FedPartSchedule, RoundSpec
 from repro.launch import steps
 from repro.models import api
@@ -409,6 +410,7 @@ def main(argv=None) -> int:
                     help="availability trace file (.npz or JSON with "
                          "duty/phase arrays) for --trace file")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.sim_clients > 0 or args.population > 0:
         return run_simulation(args)

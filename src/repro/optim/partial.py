@@ -29,6 +29,7 @@ import jax.numpy as jnp
 
 from repro.core import masking
 from repro.core.partition import Partition
+from repro.kernels import default_interpret
 from repro.kernels.masked_adam import ops as madam_ops
 from repro.kernels.masked_adam.kernel import LANES, masked_adam_kernel
 from repro.optim.adam import AdamConfig, AdamState, adam_init, adam_update
@@ -76,7 +77,7 @@ def fused_masked_step(
     pg, _ = madam_ops.pack(grads, block_rows)
     scalars = madam_ops.adam_scalars(step, cfg.lr, cfg.b1, cfg.b2, cfg.eps)
     if interpret is None:
-        interpret = madam_ops.default_interpret()
+        interpret = default_interpret()
     np_, nm, nv = masked_adam_kernel(
         pp, pg, opt_state.m, opt_state.v, jnp.asarray(bm), scalars,
         b1=cfg.b1, b2=cfg.b2, block_rows=block_rows, interpret=interpret,

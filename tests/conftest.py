@@ -1,8 +1,8 @@
-import os
-
 import jax
 import jax.numpy as jnp
 import pytest
+
+from repro.core.compile_cache import enable_compile_cache
 
 # NOTE: no XLA_FLAGS here — smoke tests and benches see 1 device; only
 # launch/dryrun.py (run as its own process) forces 512 host devices.
@@ -11,9 +11,7 @@ import pytest
 # different jit instances — e.g. the eval fn across every run_federated call,
 # or a step fn shared by two tests — compile once per machine instead of once
 # per LocalTrainer.  This is what keeps the tier-1 lane fast.
-_CACHE_DIR = os.path.join(os.path.dirname(__file__), os.pardir, ".jax_cache")
-jax.config.update("jax_compilation_cache_dir", os.path.abspath(_CACHE_DIR))
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.2)
+enable_compile_cache()
 
 
 def pytest_collection_modifyitems(config, items):

@@ -55,7 +55,7 @@ print(json.dumps({
 
 
 def test_small_mesh_dryrun_compiles():
-    env = dict(os.environ)
+    env = {**os.environ, "JAX_PLATFORMS": "cpu"}
     res = subprocess.run(
         [sys.executable, "-c", SCRIPT],
         capture_output=True, text=True, env=env, cwd=os.path.dirname(os.path.dirname(__file__)),

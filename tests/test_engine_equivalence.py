@@ -393,8 +393,8 @@ import sys, json
 sys.path.insert(0, "src")
 import jax
 import numpy as np
-jax.config.update("jax_compilation_cache_dir", os.path.abspath(".jax_cache"))
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.2)
+from repro.core.compile_cache import enable_compile_cache
+enable_compile_cache()
 
 from repro.core.schedule import FedPartSchedule
 from repro.data import (VisionDatasetSpec, balanced_eval_set, build_clients,
@@ -471,6 +471,7 @@ def _run_subprocess_script(script):
     res = subprocess.run(
         [sys.executable, "-c", script], capture_output=True, text=True,
         cwd=os.path.dirname(os.path.dirname(__file__)), timeout=900,
+        env={**os.environ, "JAX_PLATFORMS": "cpu"},
     )
     assert res.returncode == 0, res.stderr[-3000:]
     return json.loads(res.stdout.strip().splitlines()[-1])
@@ -497,8 +498,8 @@ import sys, json
 sys.path.insert(0, "src")
 import jax
 import numpy as np
-jax.config.update("jax_compilation_cache_dir", os.path.abspath(".jax_cache"))
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.2)
+from repro.core.compile_cache import enable_compile_cache
+enable_compile_cache()
 
 from repro.core.schedule import FedPartSchedule
 from repro.data import (VisionDatasetSpec, balanced_eval_set, build_clients,
@@ -765,8 +766,8 @@ import sys, json
 sys.path.insert(0, "src")
 import jax
 import numpy as np
-jax.config.update("jax_compilation_cache_dir", os.path.abspath(".jax_cache"))
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.2)
+from repro.core.compile_cache import enable_compile_cache
+enable_compile_cache()
 
 from repro.core.schedule import FedPartSchedule
 from repro.data import (VisionDatasetSpec, balanced_eval_set, build_clients,

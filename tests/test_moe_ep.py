@@ -62,6 +62,7 @@ def test_ep_matches_auto():
     res = subprocess.run(
         [sys.executable, "-c", SCRIPT], capture_output=True, text=True,
         cwd=os.path.dirname(os.path.dirname(__file__)), timeout=480,
+        env={**os.environ, "JAX_PLATFORMS": "cpu"},
     )
     assert res.returncode == 0, res.stderr[-3000:]
     out = json.loads(res.stdout.strip().splitlines()[-1])

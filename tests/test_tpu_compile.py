@@ -1,0 +1,114 @@
+"""Compile the repo's Pallas kernels for a described TPU v5e, with no chip.
+
+The TPU compiler is installed with JAX and compiles for a topology that is
+described rather than attached, so a layout Mosaic refuses (a block shape off
+the (8, 128) tiling, a rank-1 SMEM block) fails here on the CPU instead of on
+the chip.  Nothing runs: these tests say nothing about results or speed.
+
+The topology is described inside a module-scoped fixture, never while a
+module is imported: only one process at a time may load the TPU library, and
+every test worker imports this file.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.fl import resnet_task
+from repro.kernels.flash_attention import ops as fa_ops
+from repro.kernels.masked_adam import ops as madam_ops
+from repro.kernels.masked_adam.kernel import LANES, masked_adam_kernel
+from repro.kernels.ssd_chunk import ops as ssd_ops
+
+BLOCK_ROWS = 8
+COHORT = 8
+SEQ = 2048
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler can be loaded here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # A compile for a described chip is written to the persistent cache but
+    # cannot be read back without one: keep the cache out of it.
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def resnet18_rows():
+    """Packed (rows, 128) size of ResNet-18 at CIFAR-100 width, from shapes."""
+    adapter = resnet_task("resnet18", num_classes=100)
+    shapes = jax.eval_shape(adapter.init, jax.random.key(0))
+    return madam_ops.packed_rows(shapes, BLOCK_ROWS)
+
+
+def _compile(fn, *args):
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+def _adam(p, g, m, v, mask, sc):
+    return masked_adam_kernel(p, g, m, v, mask, sc, block_rows=BLOCK_ROWS,
+                              interpret=False)
+
+
+def _adam_args(one_chip, rows, lead=(), mask_lead=()):
+    def s(shape, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    t = s(lead + (rows, LANES))
+    return (t, t, t, t, s(mask_lead + (rows // BLOCK_ROWS,), jnp.int32),
+            s(lead + (4,)))
+
+
+def test_masked_adam_compiles_at_resnet18_size(one_chip, resnet18_rows):
+    assert resnet18_rows > 80_000
+    _compile(_adam, *_adam_args(one_chip, resnet18_rows))
+
+
+@pytest.mark.parametrize("batched_mask", [False, True],
+                         ids=["one_group_mask", "per_client_plan_mask"])
+def test_masked_adam_vmapped_over_cohort_compiles(one_chip, resnet18_rows,
+                                                  batched_mask):
+    """The engines vmap the kernel over the cohort: a shared mask for a
+    homogeneous round, one mask per client for a layer-plan round, and Adam
+    scalars per client always (each client keeps its own step count)."""
+    mask_axis = 0 if batched_mask else None
+    fn = jax.vmap(_adam, in_axes=(0, 0, 0, 0, mask_axis, 0))
+    args = _adam_args(one_chip, resnet18_rows, lead=(COHORT,),
+                      mask_lead=(COHORT,) if batched_mask else ())
+    _compile(fn, *args)
+
+
+@pytest.mark.parametrize("head_dim", [64, 128])
+def test_flash_attention_forward_compiles(one_chip, head_dim):
+    x = jax.ShapeDtypeStruct((1, SEQ, 4, head_dim), jnp.bfloat16,
+                             sharding=one_chip)
+    _compile(lambda q, k, v: fa_ops.flash_attention(q, k, v, interpret=False),
+             x, x, x)
+
+
+def test_ssd_chunk_compiles(one_chip):
+    x = jax.ShapeDtypeStruct((1, SEQ, 4, 128), jnp.float32, sharding=one_chip)
+    log_a = jax.ShapeDtypeStruct((1, SEQ, 4), jnp.float32, sharding=one_chip)
+    _compile(lambda q, k, v, a: ssd_ops.ssd_scan(q, k, v, a, chunk=128,
+                                                 interpret=False),
+             x, x, x, log_a)
