@@ -11,12 +11,17 @@ place, computed in bfloat16 (the control), training on half of every batch
 (a fault) and averaging half of the cohort (a fault); and it reads the
 program with its eval answer altered by an eighth of the eval set (a
 fault).  A run that leaves the state
-unchanged reads 1 on ``update_gap`` and ``change_gap`` by their definition.
+unchanged reads 1 on the norm and direction gaps (``UNCHANGED_READS_ONE``) by
+their definition.
 
 Each reading is one JSON line in ``--out``; the last line on standard output
 gives, per number, the readings its limit is set from and the limit
 (``derive_limits``), which ``--limits-out`` writes as the cell's limits
 file.
+
+A look at where the sound gaps come from runs the program's matmuls and
+convolutions at another precision: ``JAX_DEFAULT_MATMUL_PRECISION=highest``
+in the environment (the reference states its precision itself).
 """
 
 from __future__ import annotations
@@ -34,28 +39,26 @@ from pathlib import Path  # noqa: E402
 ROOT = Path(__file__).resolve().parents[1]
 sys.path[0] = str(ROOT)
 sys.path.insert(1, str(ROOT / "src"))
-# JAX's persistent compile cache lives inside the checkout, at a fixed path,
-# whatever the environment says: the program's cache helper takes the
-# directory this variable names.
-os.environ["JAX_COMPILATION_CACHE_DIR"] = str(ROOT / ".jax_cache")
+# JAX's persistent compile cache lives inside the checkout, where the
+# program's cache helper puts it when this variable is unset; a directory
+# named from outside could be shared with another checkout's runs.
+os.environ.pop("JAX_COMPILATION_CACHE_DIR", None)
 # libtpu would otherwise write its logs under a fixed path in /tmp.
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
 ALTERED_ANSWER = 0.125      # added to each compared round's eval accuracy
+# Numbers that read exactly 1 when the program leaves its state unchanged.
+UNCHANGED_READS_ONE = ("update_gap", "change_gap", "update_gap_tree",
+                       "change_gap_tree", "update_dir_gap", "change_dir_gap")
 
 
-def readings(cell, seed: int, faults: bool, precision: str | None) -> list[dict]:
-    import jax
+def readings(cell, seed: int, faults: bool) -> list[dict]:
     import jax.numpy as jnp
 
     from bench import correct, harness
 
-    if precision:
-        with jax.default_matmul_precision(precision):
-            record = harness.drive(cell, seed, 0.0, False,
-                                   setup=harness.COMPARED_ROUNDS)
-    else:
-        record = harness.drive(cell, seed, 0.0, False, setup=harness.COMPARED_ROUNDS)
+    record = harness.drive(cell, seed, 0.0, False, setup=harness.COMPARED_ROUNDS,
+                           whole_cycles=False)
     run, prog = record["run"], record["prog"]
     groups = [s.group for s in record["rounds"].issued[:harness.COMPARED_ROUNDS]]
     start = cell.reference.flatten(run["start"])
@@ -67,8 +70,7 @@ def readings(cell, seed: int, faults: bool, precision: str | None) -> list[dict]
     t = time.perf_counter()
     ref = first_last(harness.reference_run(cell, run, seed, groups))
     ref_s = time.perf_counter() - t
-    rows = [dict(kind=f"program_{precision}" if precision else "program",
-                 seed=seed, reference_s=ref_s,
+    rows = [dict(kind="program", seed=seed, reference_s=ref_s,
                  **correct.numbers(prog, ref, start, eval_n))]
     if faults:
         altered = dict(prog, acc=[a + ALTERED_ANSWER for a in prog["acc"]])
@@ -92,9 +94,6 @@ def main(argv=None) -> int:
     ap.add_argument("--out", required=True)
     ap.add_argument("--limits-out", default=None,
                     help="write the derived limits here (bench/limits/<cell>.json)")
-    ap.add_argument("--precision", default=None,
-                    help="run the program under this default matmul precision "
-                         "(a look at where the sound gaps come from)")
     args = ap.parse_args(argv)
 
     import jax
@@ -110,8 +109,7 @@ def main(argv=None) -> int:
     rows = []
     with open(args.out, "w") as f:
         for i in range(args.seeds):
-            for row in readings(cell, args.first_seed + 7919 * i, i < args.faults,
-                                args.precision):
+            for row in readings(cell, args.first_seed + 7919 * i, i < args.faults):
                 print(json.dumps(row), file=f, flush=True)
                 print(row["kind"], row["seed"], row["values"], flush=True)
                 rows.append(row)
@@ -143,7 +141,7 @@ def derive_limits(rows: list[dict]) -> dict:
             by.setdefault(r["kind"], []).append(r["values"][name])
         lower = max(by.pop("program"))
         readings = {k: min(v) for k, v in by.items()}
-        if name in ("update_gap", "change_gap", "update_gap_tree", "change_gap_tree"):
+        if name in UNCHANGED_READS_ONE:
             readings["state_unchanged"] = 1.0
         uppers = [v for k, v in readings.items()
                   if v >= (3 if k in ("control_bf16", "state_unchanged") else 10) * lower]

@@ -12,6 +12,17 @@ Numbers compared (each against the limit in ``bench/limits/<cell>.json``):
   The first round's change is what the server gets from the clients'
   optimizers after their first local round; their state never leaves them.
 - ``change_gap``: the same for the change over all the compared rounds.
+- ``client_loss_gap_first``: the largest relative gap of one client's mean
+  loss in the first round.  The first round starts from the same weights on
+  both sides, so only the forward's rounding and the first steps' drift
+  separate a sound program from the reference; a client that trains on part
+  of its batches reads the sampling error of its loss.
+- ``update_dir_gap`` and ``change_dir_gap``: over the leaves the reference
+  moved, the norm of the difference between the program's and the
+  reference's weights after the first round (after all compared rounds),
+  over the norm of the reference's change.  Adam's normalised steps keep the
+  norms of a change whatever gradient drives it; the direction shows a
+  gradient taken on other rows or in another precision.
 
 Leaves that the reference's gradient leaves at rounding level (norm under a
 thousandth of the median leaf's at the first batch) move by round-off alone
@@ -26,9 +37,9 @@ import math
 import numpy as np
 
 TINY_GRAD = 1e-3
-NUMBERS = ("loss_gap", "client_loss_gap", "acc_gap",
-           "update_gap", "update_gap_median", "update_gap_tree",
-           "change_gap", "change_gap_median", "change_gap_tree")
+NUMBERS = ("loss_gap", "client_loss_gap", "client_loss_gap_first", "acc_gap",
+           "update_gap", "update_gap_median", "update_gap_tree", "update_dir_gap",
+           "change_gap", "change_gap_median", "change_gap_tree", "change_dir_gap")
 
 
 def counted_leaves(grad_norms: dict[str, float]) -> list[str]:
@@ -68,11 +79,16 @@ def numbers(prog: dict, ref: dict, start: dict, eval_n: int) -> dict:
         out[f"{name}_gap_median"] = float(np.median([gaps[k] for k in moved]))
         tp, tr = (float(np.sqrt(sum(d[k] ** 2 for k in moved))) for d in (dp, dr))
         out[f"{name}_gap_tree"] = abs(tp - tr) / tr
+        diff = np.sqrt(sum(float(np.sum((prog["params"][i][k] - ref["params"][i][k]) ** 2))
+                           for k in moved))
+        out[f"{name}_dir_gap"] = float(diff) / tr
         norms[name] = {k: (dp[k], dr[k]) for k in moved}
     out["loss_gap"] = max(abs(a - b) / abs(b) for a, b in zip(prog["loss"], ref["loss"]))
     out["client_loss_gap"] = max(
         abs(a - b) / abs(b) for pr, rr in zip(prog["client_loss"], ref["client_loss"])
         for a, b in zip(pr, rr))
+    out["client_loss_gap_first"] = max(
+        abs(a - b) / abs(b) for a, b in zip(prog["client_loss"][0], ref["client_loss"][0]))
     out["acc_gap"] = max(abs(a - b) * eval_n for a, b in zip(prog["acc"], ref["acc"]))
     if not all(math.isfinite(v) for v in out.values()):
         out = {k: float("inf") for k in out}

@@ -15,7 +15,8 @@ metric.
 The window drives ``repro.fl.server.run_federated`` (sync runtime), one call
 per run.  Its schedule is a ``Rounds`` object: it first yields one round of
 every distinct program the window uses (and at least the three rounds the
-reference follows), then window rounds until the window's time is up.
+reference follows), then window rounds until the window's time is up and the
+schedule's cycle is whole, so that every group's round counts alike.
 ``run_federated`` reads each round's losses and eval back to the host before
 it asks for the next round, so the moment it asks is the moment the previous
 round has finished on the device.
@@ -146,14 +147,18 @@ def round_groups(schedule: str, num_groups: int) -> list[int]:
 class Rounds:
     """The schedule handed to ``run_federated``: ``setup`` rounds, then
     window rounds until ``seconds`` have passed (or, when tracing, until
-    ``trace_rounds`` rounds have run under the profiler).
+    ``trace_rounds`` rounds have run under the profiler) and the window holds
+    whole cycles of the schedule (unless ``whole_cycles`` is off, as for the
+    comparison's own readings, which need one round past the compared ones).
 
     ``run_federated`` iterates it once for the rounds and again, after the
     last, for its cost books; the second pass replays the rounds issued."""
 
     def __init__(self, cycle: list[int], setup: int, seconds: float, *,
-                 trace_rounds: int = 0, trace_dir: Path | None = None):
+                 trace_rounds: int = 0, trace_dir: Path | None = None,
+                 whole_cycles: bool = True):
         self.cycle, self.setup, self.seconds = cycle, setup, seconds
+        self.period = len(cycle) if whole_cycles else 1
         self.trace_rounds, self.trace_dir = trace_rounds, trace_dir
         self.issued: list = []
         self.ends: list[float] = []      # host time at which each round was done
@@ -210,8 +215,9 @@ class Rounds:
                 self.window_start = now
             elif i > self.setup:
                 done = i - self.setup
-                if (done >= self.trace_rounds if self.trace_dir is not None
-                        else now - self.window_start >= self.seconds):
+                enough = (done >= self.trace_rounds if self.trace_dir is not None
+                          else now - self.window_start >= self.seconds)
+                if enough and done % self.period == 0:
                     if self.trace_dir is not None:
                         jax.profiler.stop_trace()
                     self.window_end = now
@@ -319,7 +325,7 @@ def peak_bytes(chips: int) -> int:
 
 
 def drive(cell: Cell, seed: int, seconds: float, trace: bool,
-          setup: int | None = None) -> dict:
+          setup: int | None = None, whole_cycles: bool = True) -> dict:
     """Set-up and window through ``run_federated``; returns the record of
     the run (the program's first rounds included).  ``setup`` rounds default
     to one of every distinct program and at least the compared rounds."""
@@ -336,7 +342,7 @@ def drive(cell: Cell, seed: int, seconds: float, trace: bool,
         trace_dir = TRACE_DIR
     rounds = Rounds(cycle, setup, seconds,
                     trace_rounds=max(cell.traffic["trace_rounds"], len(cycle)),
-                    trace_dir=trace_dir)
+                    trace_dir=trace_dir, whole_cycles=whole_cycles)
     with tapped_engine({1, COMPARED_ROUNDS}) as tap:
         result = run_federated(run["adapter"], run["clients"], run["eval_set"],
                                rounds, run["run_cfg"],

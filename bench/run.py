@@ -31,10 +31,10 @@ ROOT = Path(__file__).resolve().parents[1]
 # modules are imported as ``bench.*`` and the program from ``src``.
 sys.path[0] = str(ROOT)
 sys.path.insert(1, str(ROOT / "src"))
-# JAX's persistent compile cache lives inside the checkout, at a fixed path,
-# whatever the environment says: the program's cache helper takes the
-# directory this variable names.
-os.environ["JAX_COMPILATION_CACHE_DIR"] = str(ROOT / ".jax_cache")
+# JAX's persistent compile cache lives inside the checkout, where the
+# program's cache helper puts it when this variable is unset; a directory
+# named from outside could be shared with another checkout's runs.
+os.environ.pop("JAX_COMPILATION_CACHE_DIR", None)
 # libtpu would otherwise write its logs under a fixed path in /tmp.
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 

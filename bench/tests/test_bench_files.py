@@ -116,3 +116,24 @@ def test_the_command_refuses_a_machine_without_a_tpu(tmp_path):
     assert out.returncode != 0
     assert out.stdout.strip() == ""
     assert "no TPU" in out.stderr
+
+
+def test_the_command_fails_without_the_program(tmp_path):
+    """A directory that holds only BENCHMARK.json and the benchmark's files
+    runs nothing and prints no result."""
+    import os
+    import subprocess
+    import sys
+
+    shutil.copy(ROOT / "BENCHMARK.json", tmp_path / "BENCHMARK.json")
+    shutil.copytree(ROOT / "bench", tmp_path / "bench",
+                    ignore=shutil.ignore_patterns("tests", ".trace", "__pycache__"))
+    env = {k: v for k, v in os.environ.items() if k != "PYTHONPATH"}
+    cmd = BENCH["command"] + ["--workload", CELLS[0], "--seed", str(2**31 + 5),
+                              "--seconds", "1", "--trace", "0"]
+    cmd[0] = sys.executable
+    out = subprocess.run(cmd, cwd=tmp_path, env=dict(env, JAX_PLATFORMS="cpu"),
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode != 0
+    assert out.stdout.strip() == ""
+    assert "No module named 'repro'" in out.stderr

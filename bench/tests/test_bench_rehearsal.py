@@ -60,11 +60,18 @@ def test_rounds_yield_setup_then_a_timed_window_and_replay():
     assert seen[:4] == [(0, 0), (1, 1), (2, 2), (3, 3)]
     assert [s[0] for s in seen] == list(range(len(seen)))
     n = len(rounds.window_rounds)
-    assert 3 <= n <= 7 and len(rounds) == len(seen)
+    # the window outlasts its seconds and ends on a whole cycle of groups
+    assert n >= 4 and n % 4 == 0 and len(rounds) == len(seen)
+    assert [s.group for s in rounds.window_rounds] == [0, 1, 2, 3] * (n // 4)
     assert rounds.window_end - rounds.window_start >= 0.05
     assert sum(rounds.window_round_s) == pytest.approx(
         rounds.window_end - rounds.window_start)
     assert [(s.index, s.group) for s in rounds] == seen      # second pass
+
+
+def test_a_window_without_whole_cycles_ends_after_one_round():
+    rounds = harness.Rounds([0, 1, 2, 3], setup=3, seconds=0.0, whole_cycles=False)
+    assert [s.group for s in rounds] == [0, 1, 2, 3]
 
 
 def test_fnu_rounds_are_full_network():
