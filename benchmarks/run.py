@@ -31,7 +31,6 @@ BENCHES = [
     "population_bench",
     "compress_bench",
     "kernels_bench",
-    "roofline",
 ]
 
 

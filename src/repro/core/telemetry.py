@@ -1,5 +1,7 @@
-"""Training telemetry: update-step-size tracking (Fig. 1) and the
-Monte-Carlo estimate of the mask-uniformity constant k (Appendix G).
+"""Training telemetry: update-step-size tracking (Fig. 1), the async
+runtime's virtual-clock timeline, the profiler spans and device scopes of a
+federated round, and the Monte-Carlo estimate of the mask-uniformity
+constant k (Appendix G).
 """
 
 from __future__ import annotations
@@ -15,6 +17,44 @@ from repro.core import masking
 from repro.core.partition import Partition
 
 PyTree = Any
+
+
+# ---------------------------------------------------------------------------
+# Profiler spans and device scopes (docs/ARCHITECTURE.md, "Observing a run")
+# ---------------------------------------------------------------------------
+
+#: Every host span (``fl.*``, opened with :func:`span` on the thread that
+#: runs the rounds) and device scope (``jax.named_scope``, carried in each
+#: XLA op's name-stack path) that a federated round puts into a profiler
+#: trace.  Round and group numbers travel as span arguments, never in names.
+SPANS: dict[str, str] = {
+    "fl.round": "one round of run_federated's sync loop (args round, group, "
+                "phase); parent of every other fl.* span",
+    "fl.sample": "cohort size, sampling without replacement, per-client "
+                 "round seeds",
+    "fl.client_data": "the cohort's datasets from the population, their "
+                      "weights, MOON previous models",
+    "fl.stack": "numpy shuffling and stacking of the cohort's batches into "
+                "buckets (args clients, buckets)",
+    "fl.dispatch": "one call into a jitted program: argument handling, "
+                   "host-to-device copies, enqueue (arg program = local, tx "
+                   "or agg)",
+    "fl.wait": "the host blocked on a device result (arg what = losses or "
+               "eval)",
+    "fl.eval": "dispatch and readback of the round's eval",
+    "grad": "device scope: forward and backward of a local step "
+            "(jax.value_and_grad)",
+    "masked_adam": "device scope: the Pallas masked-Adam kernel, from its "
+                   "pallas_call's name",
+}
+
+
+def span(name: str, **args) -> jax.profiler.TraceAnnotation:
+    """Host span ``name`` (a key of ``SPANS``) as a context manager, with
+    ``args`` as its trace metadata.  The profiler owns the clock, storage and
+    export; with no profiler session active the span records nothing and
+    costs the profiler's enabled check."""
+    return jax.profiler.TraceAnnotation(name, **args)
 
 
 # ---------------------------------------------------------------------------

@@ -107,6 +107,7 @@ from repro.core.compat import abstract_client_mesh
 from repro.core.compat import shard_map as _shard_map
 from repro.core.partition import Partition
 from repro.core.schedule import FULL_NETWORK, RoundSpec, round_base_mask
+from repro.core.telemetry import span
 from repro.data.pipeline import ClientDataset, stack_client_batches
 from repro.fl.algorithms import AlgoConfig
 from repro.fl.client import LocalTrainer
@@ -597,9 +598,12 @@ class _BatchedEngineBase(_CompressionState):
         is the MOON previous-local-model argument: stacked per client (padding
         clients fall back to the global model) when ``use_prev``, else the
         global params broadcast unbatched."""
-        for bucket in stack_client_batches(
-            datasets, batch_size, epochs, seeds, pad_clients_to=pad_clients_to
-        ):
+        with span("fl.stack", clients=len(datasets)) as stack:
+            buckets = stack_client_batches(
+                datasets, batch_size, epochs, seeds,
+                pad_clients_to=pad_clients_to)
+            stack.set_metadata(buckets=len(buckets))
+        for bucket in buckets:
             if use_prev:
                 prevs = [
                     prev_params[p] if prev_params is not None and prev_params[p] is not None else params
@@ -703,9 +707,10 @@ class _BatchedEngineBase(_CompressionState):
                 args = (params, bucket.inputs, bucket.labels,
                         bucket.step_valid, prev_arg,
                         self._bucket_gmask(plan, bucket))
-            args = self._place_cohort_args(args, submesh,
-                                           stacked_prev=use_prev)
-            locals_stacked, bucket_losses = fn(*args)
+            with span("fl.dispatch", program="local"):
+                args = self._place_cohort_args(args, submesh,
+                                               stacked_prev=use_prev)
+                locals_stacked, bucket_losses = fn(*args)
             n = bucket.num_real
             parts.append((bucket.members, (
                 jax.tree.map(lambda x: x[:n], locals_stacked), bucket_losses[:n],
@@ -732,7 +737,8 @@ class _BatchedEngineBase(_CompressionState):
         stacked, losses_dev = self.run_local_async(
             params, spec, datasets, seeds=seeds, epochs=epochs,
             batch_size=batch_size, prev_params=prev_params, plan=plan)
-        return stacked, [float(x) for x in np.asarray(losses_dev)]
+        with span("fl.wait", what="losses"):
+            return stacked, [float(x) for x in np.asarray(losses_dev)]
 
 
 @dataclasses.dataclass
@@ -917,38 +923,43 @@ class VmapEngine(_BatchedEngineBase):
             params, datasets, batch_size=batch_size, epochs=epochs, seeds=seeds,
             prev_params=prev_params, use_prev=use_prev,
         ):
-            if plan is None:
-                fn = self._local_fn(group, stacked_prev=use_prev)
-                locals_stacked, bucket_losses = fn(
-                    params, bucket.inputs, bucket.labels, bucket.step_valid,
-                    prev_arg)
-            else:
-                fn = self._plan_local_fn(stacked_prev=use_prev)
-                locals_stacked, bucket_losses = fn(
-                    params, bucket.inputs, bucket.labels, bucket.step_valid,
-                    prev_arg, self._bucket_gmask(plan, bucket))
+            with span("fl.dispatch", program="local"):
+                if plan is None:
+                    fn = self._local_fn(group, stacked_prev=use_prev)
+                    locals_stacked, bucket_losses = fn(
+                        params, bucket.inputs, bucket.labels,
+                        bucket.step_valid, prev_arg)
+                else:
+                    fn = self._plan_local_fn(stacked_prev=use_prev)
+                    locals_stacked, bucket_losses = fn(
+                        params, bucket.inputs, bucket.labels,
+                        bucket.step_valid, prev_arg,
+                        self._bucket_gmask(plan, bucket))
             parts.append((bucket.members, (locals_stacked, bucket_losses)))
 
         stacked, losses_dev = self._gather_order(parts, num)
         agg_in = stacked                 # MOON keeps the TRUE locals below
         if self.compression is not None:
             res = self._stacked_residuals(ids, range(num), num, params)
-            if plan is None:
-                agg_in, new_res = self._tx_fn(group)(params, stacked, res)
-            else:
-                agg_in, new_res = self._plan_tx_fn()(
-                    params, stacked, res, jnp.asarray(plan, jnp.float32))
+            with span("fl.dispatch", program="tx"):
+                if plan is None:
+                    agg_in, new_res = self._tx_fn(group)(params, stacked, res)
+                else:
+                    agg_in, new_res = self._plan_tx_fn()(
+                        params, stacked, res, jnp.asarray(plan, jnp.float32))
             self._store_residuals(ids, range(num), new_res)
-        if plan is None:
-            new_params = self._agg_fn(group)(
-                params, agg_in, jnp.asarray(weights, dtype=jnp.float32)
-            )
-        else:
-            new_params = self._plan_agg_fn()(
-                params, agg_in, jnp.asarray(plan, dtype=jnp.float32),
-                jnp.asarray(weights, dtype=jnp.float32)
-            )
-        losses = [float(x) for x in np.asarray(losses_dev)]
+        with span("fl.dispatch", program="agg"):
+            if plan is None:
+                new_params = self._agg_fn(group)(
+                    params, agg_in, jnp.asarray(weights, dtype=jnp.float32)
+                )
+            else:
+                new_params = self._plan_agg_fn()(
+                    params, agg_in, jnp.asarray(plan, dtype=jnp.float32),
+                    jnp.asarray(weights, dtype=jnp.float32)
+                )
+        with span("fl.wait", what="losses"):
+            losses = [float(x) for x in np.asarray(losses_dev)]
         new_locals = masking.unstack_tree(stacked, num) if use_prev else None
         return new_params, losses, new_locals
 
@@ -1424,20 +1435,21 @@ class ShardMapEngine(_BatchedEngineBase):
             if self.compression is not None:
                 res_args = (self._stacked_residuals(
                     ids, bucket.members, bucket.num_clients, params),)
-            if plan is None:
-                wb = np.zeros(bucket.num_clients, dtype=np.float32)
-                wb[: bucket.num_real] = w_norm[list(bucket.members)]
-                fn = self._local_fn(group, stacked_prev=use_prev)
-                out = fn(params, bucket.inputs, bucket.labels,
-                         bucket.step_valid, prev_arg, wb, *res_args)
-            else:
-                wb = np.zeros((bucket.num_clients, plan.shape[1]),
-                              dtype=np.float32)
-                wb[: bucket.num_real] = eff_norm[list(bucket.members)]
-                fn = self._plan_local_fn(stacked_prev=use_prev)
-                out = fn(params, bucket.inputs, bucket.labels,
-                         bucket.step_valid, prev_arg,
-                         self._bucket_gmask(plan, bucket), wb, *res_args)
+            with span("fl.dispatch", program="local"):
+                if plan is None:
+                    wb = np.zeros(bucket.num_clients, dtype=np.float32)
+                    wb[: bucket.num_real] = w_norm[list(bucket.members)]
+                    fn = self._local_fn(group, stacked_prev=use_prev)
+                    out = fn(params, bucket.inputs, bucket.labels,
+                             bucket.step_valid, prev_arg, wb, *res_args)
+                else:
+                    wb = np.zeros((bucket.num_clients, plan.shape[1]),
+                                  dtype=np.float32)
+                    wb[: bucket.num_real] = eff_norm[list(bucket.members)]
+                    fn = self._plan_local_fn(stacked_prev=use_prev)
+                    out = fn(params, bucket.inputs, bucket.labels,
+                             bucket.step_valid, prev_arg,
+                             self._bucket_gmask(plan, bucket), wb, *res_args)
             update, bucket_losses = out[0], out[1]
             updates.append(update)
             n = bucket.num_real
@@ -1450,13 +1462,16 @@ class ShardMapEngine(_BatchedEngineBase):
             if self.compression is not None:
                 self._store_residuals(ids, bucket.members, out[-1])
 
-        if plan is None:
-            new_params = self._splice_fn(group, len(updates))(params, updates)
-        else:
-            new_params = self._plan_splice_fn(len(updates))(
-                params, updates, trained)
+        with span("fl.dispatch", program="agg"):
+            if plan is None:
+                new_params = self._splice_fn(group, len(updates))(
+                    params, updates)
+            else:
+                new_params = self._plan_splice_fn(len(updates))(
+                    params, updates, trained)
         losses_dev = self._gather_order(loss_parts, num)
-        losses = [float(x) for x in np.asarray(losses_dev)]
+        with span("fl.wait", what="losses"):
+            losses = [float(x) for x in np.asarray(losses_dev)]
         if use_prev:
             stacked = self._gather_order(local_parts, num)
             new_locals = masking.unstack_tree(stacked, num)

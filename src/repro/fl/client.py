@@ -83,7 +83,8 @@ class LocalTrainer:
                 stats = self.adapter.stats(p, inputs)
                 return loss, stats
 
-            (loss, stats), grads = jax.value_and_grad(loss_fn, has_aux=True)(params)
+            with jax.named_scope("grad"):
+                (loss, stats), grads = jax.value_and_grad(loss_fn, has_aux=True)(params)
             new_params, new_state = adam_update(grads, opt_state, params, self.adam)
             if stats is not None:
                 new_params = masking.tree_update(new_params, stats)
@@ -107,7 +108,9 @@ class LocalTrainer:
                 stats = self.adapter.stats(p, inputs)
                 return loss, stats
 
-            (loss, stats), grads = jax.value_and_grad(loss_fn, has_aux=True)(trainable)
+            with jax.named_scope("grad"):
+                (loss, stats), grads = jax.value_and_grad(
+                    loss_fn, has_aux=True)(trainable)
             new_sub, new_state = adam_update(grads, opt_state, trainable, self.adam)
             new_params = masking.merge(new_sub, frozen)
             if stats is not None:
@@ -153,7 +156,8 @@ class LocalTrainer:
                 stats = self.adapter.stats(p, inputs)
                 return loss, stats
 
-            (loss, stats), grads = jax.value_and_grad(loss_fn, has_aux=True)(params)
+            with jax.named_scope("grad"):
+                (loss, stats), grads = jax.value_and_grad(loss_fn, has_aux=True)(params)
             sel = tuple(range(partition.num_groups)) if group is None else group
             bm = madam_ops.block_mask_for_group(
                 params, partition, sel, block_rows,
@@ -183,7 +187,8 @@ class LocalTrainer:
                 stats = self.adapter.stats(p, inputs)
                 return loss, stats
 
-            (loss, stats), grads = jax.value_and_grad(loss_fn, has_aux=True)(params)
+            with jax.named_scope("grad"):
+                (loss, stats), grads = jax.value_and_grad(loss_fn, has_aux=True)(params)
             gids = madam_ops.block_group_ids(
                 params, partition, block_rows,
                 exclude=aggregation.is_local_stat)

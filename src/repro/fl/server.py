@@ -69,7 +69,7 @@ from repro.core import compress
 from repro.core.costs import VirtualTimeModel, comm_cost, comp_cost
 from repro.core.partition import Partition, group_param_counts
 from repro.core.schedule import PlanAssigner, RoundSpec
-from repro.core.telemetry import StepSizeTracker, Timeline
+from repro.core.telemetry import StepSizeTracker, Timeline, span
 from repro.fl.algorithms import AlgoConfig
 from repro.fl.batched import make_engine
 from repro.fl.client import LocalTrainer
@@ -268,47 +268,55 @@ def run_federated(
     population = as_population(clients_data)
     n_clients = population.num_clients
     for spec in rounds:
-        t_round = time.perf_counter()
-        n_pick = resolve_cohort_size(n_clients, run_cfg.sample_fraction,
-                                     run_cfg.cohort_size)
-        picked = sample_without_replacement(rng, n_clients, n_pick)
-        if tracker is not None:
-            tracker.mark_round_boundary()
+        with span("fl.round", round=spec.index, group=spec.group,
+                  phase=spec.phase):
+            t_round = time.perf_counter()
+            with span("fl.sample"):
+                n_pick = resolve_cohort_size(n_clients, run_cfg.sample_fraction,
+                                             run_cfg.cohort_size)
+                picked = sample_without_replacement(rng, n_clients, n_pick)
+                seeds = [client_round_seed(run_cfg.seed, spec.index, ci)
+                         for ci in picked]
+            if tracker is not None:
+                tracker.mark_round_boundary()
 
-        datasets = [population.dataset(ci) for ci in picked]
-        seeds = [client_round_seed(run_cfg.seed, spec.index, ci)
-                 for ci in picked]
-        weights = [len(d) for d in datasets]
-        prevs = ([state_store.get("moon", int(ci)) for ci in picked]
-                 if is_moon else None)
+            with span("fl.client_data"):
+                datasets = [population.dataset(ci) for ci in picked]
+                weights = [len(d) for d in datasets]
+                prevs = ([state_store.get("moon", int(ci)) for ci in picked]
+                         if is_moon else None)
 
-        params, losses, new_locals = engine.run_round(
-            params,
-            spec,
-            datasets,
-            seeds=seeds,
-            weights=weights,
-            epochs=run_cfg.local_epochs,
-            batch_size=run_cfg.batch_size,
-            prev_params=prevs,
-            tracker=tracker,
-            plan=assigner.assign(spec, [int(ci) for ci in picked]),
-            client_ids=[int(ci) for ci in picked],
-        )
-        if new_locals is not None:
-            for ci, local in zip(picked, new_locals):
-                state_store.put("moon", int(ci), local)
+            params, losses, new_locals = engine.run_round(
+                params,
+                spec,
+                datasets,
+                seeds=seeds,
+                weights=weights,
+                epochs=run_cfg.local_epochs,
+                batch_size=run_cfg.batch_size,
+                prev_params=prevs,
+                tracker=tracker,
+                plan=assigner.assign(spec, [int(ci) for ci in picked]),
+                client_ids=[int(ci) for ci in picked],
+            )
+            if new_locals is not None:
+                for ci, local in zip(picked, new_locals):
+                    state_store.put("moon", int(ci), local)
 
-        entry = {"round": spec.index, "phase": spec.phase, "group": spec.group,
-                 "loss": float(np.mean(losses))}
-        if spec.index % run_cfg.eval_every == 0 or spec.index == len(rounds) - 1:
-            acc = float(eval_fn(params, eval_x[: run_cfg.eval_batch], eval_y[: run_cfg.eval_batch]))
-            entry["acc"] = acc
-        # Host wall clock, compiles included.  The losses (and the eval, when
-        # the round has one) are read back to the host, so the round's device
-        # work has finished by now; a round without an eval may leave its
-        # aggregation to be waited on by the next round.
-        entry["seconds"] = time.perf_counter() - t_round
+            entry = {"round": spec.index, "phase": spec.phase, "group": spec.group,
+                     "loss": float(np.mean(losses))}
+            if spec.index % run_cfg.eval_every == 0 or spec.index == len(rounds) - 1:
+                with span("fl.eval"):
+                    acc = eval_fn(params, eval_x[: run_cfg.eval_batch],
+                                  eval_y[: run_cfg.eval_batch])
+                    with span("fl.wait", what="eval"):
+                        entry["acc"] = float(acc)
+            # Host wall clock, compiles included.  The losses (and the eval,
+            # when the round has one) are read back to the host, so the
+            # round's device work has finished by now; a round without an
+            # eval may leave its aggregation to be waited on by the next
+            # round.
+            entry["seconds"] = time.perf_counter() - t_round
         history.append(entry)
         if verbose:
             print(f"round {spec.index:3d} [{spec.phase}:{spec.group:3d}] "

@@ -115,6 +115,9 @@ def masked_adam_kernel(
         ],
         input_output_aliases={2: 0, 4: 1, 5: 2},
         interpret=interpret,
+        # The kernel's op and a ``masked_adam`` level of its name-stack path
+        # take this name, whatever program calls it (core.telemetry.SPANS).
+        name="masked_adam",
     )(block_mask, sc_tile, p, g, m, v)
 
 

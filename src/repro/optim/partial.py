@@ -70,7 +70,8 @@ def fused_masked_step(
     """Eq. 1 through the fused kernel: full-tree gradient, block-masked
     packed Adam update, frozen blocks copy through bit-exact."""
     guard_fused_config(cfg)
-    loss, grads = jax.value_and_grad(loss_fn)(params)
+    with jax.named_scope("grad"):
+        loss, grads = jax.value_and_grad(loss_fn)(params)
     step = opt_state.step + 1
     bm = madam_ops.block_mask_for_group(params, partition, groups, block_rows)
     pp, meta = madam_ops.pack(params, block_rows)
@@ -93,7 +94,8 @@ def masked_step(
     cfg: AdamConfig,
 ) -> tuple[PyTree, AdamState, jax.Array]:
     """Eq. 1: w ← w − γ·S⊙update(∇L).  Full-tree gradient, masked update."""
-    loss, grads = jax.value_and_grad(loss_fn)(params)
+    with jax.named_scope("grad"):
+        loss, grads = jax.value_and_grad(loss_fn)(params)
     grads = masking.apply_mask(grads, mask)
     new_params, new_state = adam_update(grads, opt_state, params, cfg)
     # Mask the parameter delta too: Adam's bias correction would otherwise
@@ -123,7 +125,8 @@ def partitioned_step(
     def sub_loss(sub):
         return loss_fn(masking.merge(sub, frozen))
 
-    loss, grads = jax.value_and_grad(sub_loss)(trainable)
+    with jax.named_scope("grad"):
+        loss, grads = jax.value_and_grad(sub_loss)(trainable)
     if opt_state is None:
         opt_state = adam_init(trainable)
     new_sub, new_state = adam_update(grads, opt_state, trainable, cfg)
@@ -137,6 +140,7 @@ def full_step(
     cfg: AdamConfig,
 ) -> tuple[PyTree, AdamState, jax.Array]:
     """FNU step (FedAvg baseline)."""
-    loss, grads = jax.value_and_grad(loss_fn)(params)
+    with jax.named_scope("grad"):
+        loss, grads = jax.value_and_grad(loss_fn)(params)
     new_params, new_state = adam_update(grads, opt_state, params, cfg)
     return new_params, new_state, loss

@@ -10,6 +10,8 @@ module is imported: only one process at a time may load the TPU library, and
 every test worker imports this file.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -96,6 +98,20 @@ def test_masked_adam_vmapped_over_cohort_compiles(one_chip, resnet18_rows,
     args = _adam_args(one_chip, resnet18_rows, lead=(COHORT,),
                       mask_lead=(COHORT,) if batched_mask else ())
     _compile(fn, *args)
+
+
+def test_masked_adam_op_carries_its_name_and_scope(one_chip):
+    """The kernel's op is named after ``masked_adam`` and its name-stack
+    path holds the ``masked_adam`` scope (``core.telemetry.SPANS``) through
+    the vmap over the cohort, both from the ``pallas_call``'s name, so a
+    trace of any program finds it by name."""
+    fn = jax.vmap(_adam, in_axes=(0, 0, 0, 0, None, 0))
+    text = _compile(fn, *_adam_args(one_chip, 8 * BLOCK_ROWS,
+                                    lead=(COHORT,))).as_text()
+    [call] = [ln for ln in text.splitlines()
+              if 'custom_call_target="tpu_custom_call"' in ln]
+    assert re.match(r"\s*(ROOT )?%\S*masked_adam", call)
+    assert re.search(r'op_name="[^"]*masked_adam[^"/]*/pallas_call"', call)
 
 
 @pytest.mark.parametrize("head_dim", [64, 128])
