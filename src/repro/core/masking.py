@@ -15,7 +15,7 @@ updates.
 
 from __future__ import annotations
 
-from typing import Any, Sequence
+from typing import Any, Callable, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -78,6 +78,11 @@ def complement(params: PyTree, partition: Partition, groups: GroupSel) -> PyTree
     """Return a pruned pytree holding every leaf *not* in ``groups``."""
     sel = _as_group_set(groups)
     return _filter(params, (), lambda p: partition.group_of(p) not in sel)
+
+
+def select_where(params: PyTree, keep: Callable[[str], bool]) -> PyTree:
+    """Return a pruned pytree holding the leaves whose path ``keep`` accepts."""
+    return _filter(params, (), keep)
 
 
 def _filter(node: PyTree, prefix: Path, keep) -> PyTree:

@@ -38,7 +38,9 @@ SPANS: dict[str, str] = {
                 "buckets (args clients, buckets)",
     "fl.dispatch": "one call into a jitted program: argument handling, "
                    "host-to-device copies, enqueue (arg program = local, tx "
-                   "or agg)",
+                   "or agg; a fused local round adds kernel_rows, the packed "
+                   "rows the masked-Adam kernel streams per client-step, and "
+                   "model_rows, the whole model's)",
     "fl.wait": "the host blocked on a device result (arg what = losses or "
                "eval)",
     "fl.eval": "dispatch and readback of the round's eval",

@@ -394,6 +394,7 @@ class _BatchedEngineBase(_CompressionState):
         self._local_fns: dict[tuple[int, bool], Callable] = {}
         self._agg_fns: dict[Any, Callable] = {}
         self._cohort_fns: dict[tuple[int, bool], Callable] = {}
+        self._kernel_rows: dict[int | None, dict[str, int]] = {}
         self._init_compression_state()
         if self.fused_adam:
             guard_fused_config(self.trainer.adam)
@@ -417,16 +418,34 @@ class _BatchedEngineBase(_CompressionState):
     def _donate_params(self) -> tuple[int, ...]:
         return (0,) if self.donate else ()
 
+    def _local_span_args(self, params: PyTree, group: int, plan) -> dict:
+        """The local ``fl.dispatch`` span's args on the fused path: the
+        packed rows the kernel streams per client-step (``kernel_rows``) and
+        the whole model's (``model_rows``), from shapes alone, once per
+        program.  A partial round streams its group's trained rows; FNU and
+        plan rounds stream the whole model."""
+        if not self.fused_adam:
+            return {}
+        sel = None if plan is not None or group < 0 else group
+        if sel not in self._kernel_rows:
+            self._kernel_rows[sel] = {
+                "kernel_rows": self.trainer.fused_kernel_rows(
+                    params, sel, FUSED_BLOCK_ROWS),
+                "model_rows": madam_ops.packed_rows(params, FUSED_BLOCK_ROWS)}
+        return self._kernel_rows[sel]
+
     # -- shared local-round core -------------------------------------------
 
     @staticmethod
     def _scan_local_steps(step_fn, global_params, opt0, inputs, labels,
-                          step_valid, prev, leaf_bits=None):
+                          step_valid, prev, leaf_bits=None, init=None):
         """The shared pad-and-mask scan over (possibly padded) steps: invalid
         steps compute but their parameter/optimizer updates and losses are
         discarded.  ``leaf_bits`` (per-client layer plans) additionally masks
         each leaf's parameter update by its group's plan bit, every step —
-        frozen leaves stay re-pinned to the broadcast global."""
+        frozen leaves stay re-pinned to the broadcast global.  ``init`` is
+        the carry the steps start from when it is not the whole global tree
+        (the fused partial step's, ``LocalTrainer.fused_init``)."""
 
         def body(carry, xs):
             params, opt = carry
@@ -444,8 +463,9 @@ class _BatchedEngineBase(_CompressionState):
             opt = jax.tree.map(lambda a, b: jnp.where(keep, a, b), new_o, opt)
             return (params, opt), jnp.where(keep, loss.astype(jnp.float32), 0.0)
 
+        start = global_params if init is None else init
         (params, _), step_losses = jax.lax.scan(
-            body, (global_params, opt0), (inputs, labels, step_valid)
+            body, (start, opt0), (inputs, labels, step_valid)
         )
         mean_loss = jnp.sum(step_losses) / jnp.maximum(jnp.sum(step_valid), 1.0)
         return params, mean_loss
@@ -454,17 +474,23 @@ class _BatchedEngineBase(_CompressionState):
         """Single-client local round (``_scan_local_steps`` over the pruned
         full/partial step for ``group``).  With ``fused_adam`` the step is
         the Pallas masked-Adam kernel over the packed (rows, 128) layout
-        instead: same scan, same signature, packed optimizer state
-        (docs/KERNELS.md)."""
+        instead, with packed optimizer state (docs/KERNELS.md).  On a
+        partial round the fused scan carries only the group's trained
+        leaves, every layer's BN running moments and Adam state packed for
+        the trained leaves; the frozen leaves stay the unbatched global ones
+        and are merged back once, after the scan."""
         if self.fused_adam:
-            step_fn = self.trainer.make_fused_step(
-                None if group < 0 else group, FUSED_BLOCK_ROWS)
+            sel = None if group < 0 else group
+            trainer = self.trainer
+            step_fn = trainer.make_fused_step(sel, FUSED_BLOCK_ROWS)
 
             def one_client(global_params, inputs, labels, step_valid, prev):
-                opt0 = fused_adam_init(global_params, FUSED_BLOCK_ROWS)
-                return self._scan_local_steps(
+                carry, opt0 = trainer.fused_init(
+                    global_params, sel, FUSED_BLOCK_ROWS)
+                carry, loss = self._scan_local_steps(
                     step_fn, global_params, opt0, inputs, labels, step_valid,
-                    prev)
+                    prev, init=carry)
+                return masking.tree_update(global_params, carry), loss
 
             return one_client
 
@@ -707,7 +733,8 @@ class _BatchedEngineBase(_CompressionState):
                 args = (params, bucket.inputs, bucket.labels,
                         bucket.step_valid, prev_arg,
                         self._bucket_gmask(plan, bucket))
-            with span("fl.dispatch", program="local"):
+            with span("fl.dispatch", program="local",
+                      **self._local_span_args(params, group, plan)):
                 args = self._place_cohort_args(args, submesh,
                                                stacked_prev=use_prev)
                 locals_stacked, bucket_losses = fn(*args)
@@ -923,7 +950,8 @@ class VmapEngine(_BatchedEngineBase):
             params, datasets, batch_size=batch_size, epochs=epochs, seeds=seeds,
             prev_params=prev_params, use_prev=use_prev,
         ):
-            with span("fl.dispatch", program="local"):
+            with span("fl.dispatch", program="local",
+                      **self._local_span_args(params, group, plan)):
                 if plan is None:
                     fn = self._local_fn(group, stacked_prev=use_prev)
                     locals_stacked, bucket_losses = fn(
@@ -1435,7 +1463,8 @@ class ShardMapEngine(_BatchedEngineBase):
             if self.compression is not None:
                 res_args = (self._stacked_residuals(
                     ids, bucket.members, bucket.num_clients, params),)
-            with span("fl.dispatch", program="local"):
+            with span("fl.dispatch", program="local",
+                      **self._local_span_args(params, group, plan)):
                 if plan is None:
                     wb = np.zeros(bucket.num_clients, dtype=np.float32)
                     wb[: bucket.num_real] = w_norm[list(bucket.members)]

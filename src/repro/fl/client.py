@@ -15,6 +15,7 @@ from typing import Any, Callable, Sequence
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import aggregation, masking
 from repro.core.partition import Partition
@@ -137,36 +138,106 @@ class LocalTrainer:
         )
         return madam_ops.unpack(np_, meta), AdamState(step_i, nm, nv)
 
+    def _fused_split(self, params, group):
+        """``params`` as three pruned trees for the subtree fused step: the
+        trained leaves of ``group`` (an int or a set of group ids), BN
+        running moments left out; every layer's running moments, which each
+        step's forward refreshes; and the rest, which the round leaves as
+        they are."""
+        sel = {group} if isinstance(group, int) else {int(g) for g in group}
+        stat = aggregation.is_local_stat
+
+        def in_sel(p):
+            return self.partition.group_of(p) in sel
+
+        return (
+            masking.select_where(params, lambda p: in_sel(p) and not stat(p)),
+            masking.select_where(params, stat),
+            masking.select_where(params, lambda p: not (in_sel(p) or stat(p))),
+        )
+
+    def fused_init(self, params, group=None, block_rows: int = 8):
+        """Start of a fused local round: ``(carry, opt_state)``.  For
+        ``group=None`` the carry is the whole tree and the packed Adam state
+        covers it; otherwise the carry is the group's trained leaves and
+        every layer's BN running moments, and the state covers the trained
+        leaves alone.  ``masking.tree_update(global_params, carry)`` gives the
+        full tree back."""
+        if group is None:
+            return params, fused_adam_init(params, block_rows)
+        trained, stats, _ = self._fused_split(params, group)
+        return (masking.merge(trained, stats),
+                fused_adam_init(trained, block_rows))
+
+    def fused_kernel_rows(self, params, group=None, block_rows: int = 8) -> int:
+        """Packed rows the kernel streams per step of ``make_fused_step``."""
+        if group is None:
+            return madam_ops.packed_rows(params, block_rows)
+        return madam_ops.packed_rows(
+            self._fused_split(params, group)[0], block_rows)
+
     def make_fused_step(self, group=None, block_rows: int = 8):
-        """Raw (unjitted) fused step: FNU-shaped full-tree gradient, one
-        fused kernel pass with a *static* per-block mask — ``group=None``
-        trains every layer group (FNU), an int / sequence trains that
-        homogeneous group set, and frozen blocks copy through bit-exact
-        (Eq. 1's masked form; equivalence with the pruned partial step is
-        pinned in tests).  BN running moments are excluded from the kernel
-        mask and spliced fresh from the forward pass, exactly like the
-        unfused steps.  ``opt_state`` is the packed ``fused_adam_init``
-        state."""
+        """Raw (unjitted) fused step: one fused kernel pass per step over the
+        packed (rows, 128) layout, with ``opt_state`` the packed Adam state
+        of ``fused_init``.
+
+        ``group=None`` (FNU) takes the whole-tree gradient and updates every
+        block but the BN running moments' (Eq. 1's masked form).  An int or a
+        sequence trains that homogeneous group set on the carry of
+        ``fused_init``: the gradient is taken with respect to the trained
+        leaves alone, the frozen leaves are closed over from
+        ``global_params``, and the kernel streams the trained leaves' rows
+        with every block trained.  XLA then drops the backward below the
+        group, as in ``make_partial_step``.  BN running moments stay out of
+        the kernel and are spliced fresh from the forward pass, exactly like
+        the unfused steps."""
         guard_fused_config(self.adam)
         partition = self.partition
 
-        def step(params, opt_state, inputs, labels, global_params, prev_params):
-            def loss_fn(p):
+        if group is None:
+            def step(params, opt_state, inputs, labels, global_params,
+                     prev_params):
+                def loss_fn(p):
+                    loss = self._total_loss(
+                        p, inputs, labels, global_params, prev_params)
+                    stats = self.adapter.stats(p, inputs)
+                    return loss, stats
+
+                with jax.named_scope("grad"):
+                    (loss, stats), grads = jax.value_and_grad(
+                        loss_fn, has_aux=True)(params)
+                bm = madam_ops.block_mask_for_group(
+                    params, partition, tuple(range(partition.num_groups)),
+                    block_rows, exclude=aggregation.is_local_stat)
+                new_params, new_state = self._fused_update(
+                    params, grads, opt_state, bm, block_rows)
+                if stats is not None:
+                    new_params = masking.tree_update(new_params, stats)
+                return new_params, new_state, loss
+
+            return step
+
+        def step(carry, opt_state, inputs, labels, global_params, prev_params):
+            trained, stats0, _ = self._fused_split(carry, group)
+            frozen = self._fused_split(global_params, group)[2]
+
+            def loss_fn(sub):
+                p = masking.merge(sub, stats0, frozen)
                 loss = self._total_loss(p, inputs, labels, global_params, prev_params)
                 stats = self.adapter.stats(p, inputs)
                 return loss, stats
 
             with jax.named_scope("grad"):
-                (loss, stats), grads = jax.value_and_grad(loss_fn, has_aux=True)(params)
-            sel = tuple(range(partition.num_groups)) if group is None else group
-            bm = madam_ops.block_mask_for_group(
-                params, partition, sel, block_rows,
-                exclude=aggregation.is_local_stat)
-            new_params, new_state = self._fused_update(
-                params, grads, opt_state, bm, block_rows)
+                (loss, stats), grads = jax.value_and_grad(
+                    loss_fn, has_aux=True)(trained)
+            ones = np.ones(madam_ops.packed_rows(trained, block_rows)
+                           // block_rows, np.int32)
+            new_sub, new_state = self._fused_update(
+                trained, grads, opt_state, ones, block_rows)
+            new_carry = masking.merge(new_sub, stats0)
             if stats is not None:
-                new_params = masking.tree_update(new_params, stats)
-            return new_params, new_state, loss
+                new_carry = masking.tree_update(new_carry, stats)
+            return new_carry, new_state, loss
 
         return step
 
@@ -257,9 +328,9 @@ class LocalTrainer:
         else:
             full = group < 0
         if fused:
-            opt_state = fused_adam_init(params)
-            step = self.fused_step(
-                None if full else (groups if groups is not None else group))
+            key = None if full else (groups if groups is not None else group)
+            step = self.fused_step(key)
+            params, opt_state = self.fused_init(params, key)
         elif full:
             opt_state = adam_init(params)
             step = self._full_step
@@ -269,6 +340,11 @@ class LocalTrainer:
         else:
             opt_state = adam_init(masking.select(params, self.partition, group))
             step = self.partial_step(group)
+        # ``params`` is the step's carry: the whole tree, or the fused
+        # partial step's trained leaves and BN moments (``fused_init``).
+        def full_tree(carry):
+            return masking.tree_update(global_params, carry)
+
         losses = []
         for inputs, labels in data.batches(batch_size, epochs, seed):
             before = params
@@ -277,5 +353,6 @@ class LocalTrainer:
             )
             losses.append(float(loss))
             if step_tracker is not None:
-                step_tracker.record(before, params)
-        return params, float(jnp.mean(jnp.array(losses))) if losses else 0.0
+                step_tracker.record(full_tree(before), full_tree(params))
+        return (full_tree(params),
+                float(jnp.mean(jnp.array(losses))) if losses else 0.0)

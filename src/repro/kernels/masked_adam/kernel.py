@@ -22,9 +22,11 @@ a client's step count behind), so under the engines' vmap they gain a client
 axis, and a (clients, 8, 128) array still tiles where a (clients, 4) SMEM
 array would not.
 
-NOTE (DESIGN.md §6): in the production FedPart path the *partitioned* update
-never materialises frozen tensors at all; this kernel serves the Eq. 1 masked
-semantics (reference form) and any mixed-group tensor boundary.
+NOTE: on a homogeneous FedPart partial round the engines' fused step packs
+only the trained group (``fl.client.LocalTrainer.make_fused_step``): the
+kernel streams those rows with every block trained, and frozen tensors never
+reach it.  The per-block mask serves the whole-tree masked form, which FNU
+rounds and per-client layer plans run, and any mixed-group tensor boundary.
 """
 
 from __future__ import annotations

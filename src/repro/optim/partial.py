@@ -16,8 +16,12 @@ masked-Adam kernel (``kernels/masked_adam``): params/grads are packed into
 the kernel's (rows, 128) block layout, the whole optimizer update runs as one
 fused pass with a per-block mask, and m/v live *packed* across steps
 (``fused_adam_init``).  The three-way equivalence is pinned in
-``tests/test_kernels_adam.py``; the engines' ``fused_adam=True`` path builds
-on the same step shape (docs/KERNELS.md).
+``tests/test_kernels_adam.py``.  The engines' ``fused_adam=True`` path
+(``fl.client.LocalTrainer.make_fused_step``, docs/KERNELS.md) keeps this
+whole-tree masked form for FNU rounds and per-client plans; a homogeneous
+partial round joins the two forms instead: it packs, differentiates and
+carries only the trained group, as ``partitioned_step`` does, and runs the
+kernel over that group's rows with m/v packed for them alone.
 """
 
 from __future__ import annotations
@@ -41,7 +45,8 @@ def fused_adam_init(params: PyTree, block_rows: int = 8) -> AdamState:
     """Adam state over the *packed* (rows, 128) layout: m/v are single f32
     buffers aligned with ``ops.pack(params)``, not per-leaf trees.  This is
     what keeps the fused scan pack-free for the optimizer state — only
-    params/grads are packed each step."""
+    params/grads are packed each step.  ``params`` is whatever tree the
+    step packs: the whole model, or a partial round's trained subtree."""
     rows = madam_ops.packed_rows(params, block_rows)
     z = jnp.zeros((rows, LANES), jnp.float32)
     return AdamState(step=jnp.zeros((), jnp.int32), m=z, v=jnp.zeros_like(z))

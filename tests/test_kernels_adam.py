@@ -18,6 +18,7 @@ import numpy as np
 import pytest
 
 from repro.core import masking
+from repro.core.aggregation import is_local_stat
 from repro.core.partition import build_partition
 from repro.kernels.masked_adam import ops
 from repro.kernels.masked_adam.kernel import (masked_adam_kernel,
@@ -420,3 +421,116 @@ def test_fused_masked_step_rejects_weight_decay():
         fused_masked_step(lambda p: jnp.float32(0.0), params,
                           fused_adam_init(params), part, 0,
                           AdamConfig(weight_decay=0.1))
+
+
+# ---------------------------------------------------------------------------
+# the subtree fused step (a homogeneous partial round of the fused engines)
+# ---------------------------------------------------------------------------
+
+_RN_STEPS = 3
+_RN_ADAM = AdamConfig(lr=1e-2, eps=1e-3)   # Adam's linear regime (see
+                                           # tests/test_engine_equivalence.py)
+
+
+@pytest.fixture(scope="module")
+def resnet4():
+    from repro.fl import AlgoConfig, LocalTrainer, resnet_task
+
+    adapter = resnet_task("resnet4", num_classes=4)
+    params = adapter.init(jax.random.key(0))
+    part = adapter.partition(params)
+    trainer = LocalTrainer(adapter=adapter, partition=part, algo=AlgoConfig(),
+                           adam=_RN_ADAM)
+    ks = jax.random.split(jax.random.key(3), 2)
+    xs = jax.random.normal(ks[0], (_RN_STEPS, 6, 8, 8, 3), jnp.float32)
+    ys = jax.random.randint(ks[1], (_RN_STEPS, 6), 0, 4)
+    return adapter, params, part, trainer, xs, ys
+
+
+def _leaf_paths(tree):
+    return [("/".join(str(getattr(k, "key", k)) for k in path), leaf)
+            for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]]
+
+
+def _reference_run(adapter, params, part, group, xs, ys, fused):
+    """``steps`` of the unfused ``partitioned_step`` or the whole-tree masked
+    ``fused_masked_step``, with every layer's BN running moments spliced from
+    the forward pass as the engines do."""
+    p = params
+    opt = fused_adam_init(params) if fused else None
+    for x, y in zip(xs, ys):
+        def loss_fn(q, x=x, y=y):
+            return adapter.loss(q, x, y)
+
+        stats = adapter.stats(p, x)
+        if fused:
+            p, opt, _ = fused_masked_step(loss_fn, p, opt, part, group,
+                                          _RN_ADAM)
+        else:
+            p, opt, _ = partitioned_step(loss_fn, p, part, group, opt,
+                                         _RN_ADAM)
+        p = masking.tree_update(p, stats)
+    return p
+
+
+@pytest.mark.parametrize("group", [0, 3, 5], ids=["stem_conv", "shortcut_bns",
+                                                  "head"])
+def test_subtree_fused_step_matches_partitioned_and_masked_fused(resnet4,
+                                                                 group):
+    """Over several steps, the fused step on the trained group's subtree
+    (``LocalTrainer.make_fused_step(group)``) equals the unfused pruned step
+    and the whole-tree masked fused step: trained leaves close, frozen leaves
+    bit-equal to the start, every layer's running moments refreshed."""
+    adapter, params, part, trainer, xs, ys = resnet4
+    carry, opt = trainer.fused_init(params, group)
+    assert opt.m.shape[0] == trainer.fused_kernel_rows(params, group) \
+        < ops.packed_rows(params)
+    step = jax.jit(trainer.make_fused_step(group))
+    for x, y in zip(xs, ys):
+        carry, opt, _ = step(carry, opt, x, y, params, params)
+    assert int(opt.step) == _RN_STEPS
+    got = masking.tree_update(params, carry)
+
+    for fused in (False, True):
+        want = _reference_run(adapter, params, part, group, xs, ys, fused)
+        _assert_trees_close(got, want)
+    for (path, a), (_, orig) in zip(_leaf_paths(got), _leaf_paths(params)):
+        if is_local_stat(path):
+            assert np.abs(np.asarray(a) - np.asarray(orig)).max() > 0, path
+        elif part.group_of(path) == group:
+            assert np.abs(np.asarray(a) - np.asarray(orig)).max() > 0, path
+        else:
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(orig),
+                                          err_msg=path)
+
+
+def test_subtree_fused_round_discards_padded_steps(resnet4):
+    """Through the vmap engine's fused local round: a client whose last step
+    is padded (``step_valid`` 0) ends where the subtree step leaves it after
+    its valid steps; frozen leaves come back bit-equal to the global model;
+    every layer's BN running moments move."""
+    from repro.fl import AlgoConfig, make_engine
+
+    adapter, params, part, trainer, xs, ys = resnet4
+    group = 3
+    engine = make_engine("vmap", trainer=trainer, partition=part,
+                         algo=AlgoConfig(), fused_adam=True)
+    valid = jnp.asarray([[1, 1, 0], [1, 1, 1]], jnp.float32)
+    stacked, losses = engine._local_fn(group, False)(
+        params, jnp.stack([xs, xs]), jnp.stack([ys, ys]), valid, params)
+
+    step = jax.jit(trainer.make_fused_step(group))
+    for c, n in enumerate((2, 3)):
+        carry, opt = trainer.fused_init(params, group)
+        for x, y in zip(xs[:n], ys[:n]):
+            carry, opt, _ = step(carry, opt, x, y, params, params)
+        want = masking.tree_update(params, carry)
+        got = jax.tree.map(lambda a, c=c: a[c], stacked)
+        _assert_trees_close(got, want)
+        for (path, a), (_, orig) in zip(_leaf_paths(got), _leaf_paths(params)):
+            if is_local_stat(path):
+                assert np.abs(np.asarray(a) - np.asarray(orig)).max() > 0, path
+            elif part.group_of(path) != group:
+                np.testing.assert_array_equal(np.asarray(a), np.asarray(orig),
+                                              err_msg=path)
+    assert np.all(np.isfinite(np.asarray(losses)))

@@ -128,3 +128,55 @@ def test_ssd_chunk_compiles(one_chip):
     _compile(lambda q, k, v, a: ssd_ops.ssd_scan(q, k, v, a, chunk=128,
                                                  interpret=False),
              x, x, x, log_a)
+
+
+def _kernel_p_rows(text):
+    """Rows of the masked-Adam kernel's ``p`` operand (operand 2) in a
+    compiled program's text, from the custom call's operand layouts."""
+    [call] = [ln for ln in text.splitlines()
+              if 'custom_call_target="tpu_custom_call"' in ln]
+    shapes = call.split("operand_layout_constraints={", 1)[1]
+    p = re.findall(r"\w+\[([\d,]*)\]", shapes)[2]
+    return int(p.split(",")[-2])
+
+
+def test_fused_partial_round_streams_only_the_trained_group(one_chip,
+                                                            resnet18_rows,
+                                                            monkeypatch):
+    """The vmap engine's fused local round of ResNet-18 at published widths,
+    for its largest group (14: a 3x3x512x512 convolution and its BN's scale
+    and bias, 2,360,320 parameters) against FNU's, on a small cohort: the
+    kernel's ``p`` operand holds the group's packed trained rows, not the
+    whole model's, no buffer is sized to the whole packed model, and the
+    program needs less scratch memory than FNU's."""
+    import repro.fl.client as client
+    from repro.fl import AlgoConfig, LocalTrainer, make_engine
+    from repro.optim.adam import AdamConfig
+
+    # compile the kernel, not its interpret-mode emulation
+    monkeypatch.setattr(client, "default_interpret", lambda: False)
+    adapter = resnet_task("resnet18", num_classes=100)
+    shapes = jax.eval_shape(adapter.init, jax.random.key(0))
+    part = adapter.partition(shapes)
+    trainer = LocalTrainer(adapter=adapter, partition=part, algo=AlgoConfig(),
+                           adam=AdamConfig())
+    engine = make_engine("vmap", trainer=trainer, partition=part,
+                         algo=AlgoConfig(), fused_adam=True)
+
+    def s(shape, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    clients, steps, batch = 2, 2, 8
+    params = jax.tree.map(lambda x: s(x.shape, x.dtype), shapes)
+    args = (params, s((clients, steps, batch, 32, 32, 3)),
+            s((clients, steps, batch), jnp.int32), s((clients, steps)), params)
+    partial = _compile(engine._local_fn(14, False), *args)
+    full = _compile(engine._local_fn(-1, False), *args)
+
+    trained = trainer.fused_kernel_rows(shapes, 14, BLOCK_ROWS)
+    assert trained == 18_448                 # 18,432 + 8 + 8 rows
+    assert _kernel_p_rows(partial.as_text()) == trained
+    assert _kernel_p_rows(full.as_text()) == resnet18_rows
+    assert f",{resnet18_rows},{LANES}]" not in partial.as_text()
+    assert partial.memory_analysis().temp_size_in_bytes \
+        < full.memory_analysis().temp_size_in_bytes
