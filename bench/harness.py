@@ -387,20 +387,44 @@ def compare(cell: Cell, record: dict, seed: int) -> dict:
 
 
 def layer_context(cell: Cell, record: dict, clock: CompileClock, peaks: dict) -> dict:
-    """What the per-layer metric readers read: the reduced trace, the cell,
-    the chip's peaks and the counts of the traced rounds."""
-    from bench import traces
+    """The readers' context of this run's trace (``trace_context``)."""
+    rounds = record["rounds"]
+    return trace_context(cell, next(TRACE_DIR.rglob("*.xplane.pb")), peaks,
+                         traced_groups=[s.group for s in rounds.window_rounds],
+                         setup_compile_s=clock.seconds(0.0, rounds.window_start))
 
-    tr = traces.load(str(next(TRACE_DIR.rglob("*.xplane.pb"))))
+
+def trace_context(cell: Cell, path: Path, peaks: dict, traced_groups: list[int],
+                  setup_compile_s: float) -> dict:
+    """What the per-layer metric readers read: the reduced trace
+    (``trace``), the device ops with their scope paths (``ops``, from the
+    same file; ``None`` where the XSpace module cannot be loaded, and then
+    only the readers of ``bench.scopes`` read nothing), the cell, the chip's
+    peaks and the counts of the traced rounds.
+
+    Every configuration's readers are checked on a trace of its own
+    program: ``bench/tests/data/<config>.xplane.pb.gz``, recorded on the
+    chip by ``bench/tests/record_trace.py`` from ``<config>.ctx.json``,
+    which holds the test-size model and traffic the recording ran and the
+    traced groups and set-up compile seconds it read (the arguments of this
+    function).  A configuration without that pair fails
+    ``test_metric_readers_on_the_trace``."""
+    from bench import scopes, traces
+
+    tr = traces.load(str(path))
+    try:
+        ops = scopes.load_ops(path, tr.window)
+    except ModuleNotFoundError:
+        ops = None
     ref, cfg = cell.reference, cell.config
     return dict(
-        trace=tr, cell=cell, peaks=peaks,
-        traced_groups=[s.group for s in record["rounds"].window_rounds],
+        trace=tr, ops=ops, cell=cell, peaks=peaks,
+        traced_groups=traced_groups,
         samples_per_round=samples_per_round(cell),
         client_steps=samples_per_round(cell) // (cell.traffic["cohort"] * cell.traffic["batch"]),
         group_fwd_flops=ref.group_forward_flops(cfg),
         group_trained_params=ref.group_trained_params(cfg),
-        setup_compile_s=clock.seconds(0.0, record["rounds"].window_start),
+        setup_compile_s=setup_compile_s,
     )
 
 

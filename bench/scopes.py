@@ -31,7 +31,8 @@ per traced round where the unit is ms/round:
   are left out, as ``Trace.top_ops`` leaves them out.
 
 A reading whose marks the traced program lacks is ``None``: a program
-without spans or scopes has nothing to read.
+without spans or scopes has nothing to read.  Each reading is also the
+per-layer metric of its name (``bench/metrics/<name>.py``, through ``read``).
 
     python3 bench/scopes.py [trace.xplane.pb]
 
@@ -265,6 +266,16 @@ def readings(trace: traces.Trace, ops: dict[str, list[Op]]) -> dict:
         ("grad_ms", "masked_adam_ms", "step_overhead_ms"))
     return {"host_prep_ms": host_prep_ms(trace),
             "idle_host_prep_frac": idle_host_prep_frac(trace), **split}
+
+
+def read(ctx: dict, name: str) -> float | None:
+    """The reading ``name`` for its per-layer metric reader
+    (``bench/metrics/<name>.py``): ``None`` where the harness could not read
+    the op paths (``ctx["ops"]`` is ``None``) or the program lacks the
+    marks."""
+    if ctx.get("ops") is None:
+        return None
+    return readings(ctx["trace"], ctx["ops"])[name]
 
 
 def main(argv=None) -> int:

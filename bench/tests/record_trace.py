@@ -1,12 +1,21 @@
-"""Record the small trace that ``test_bench_scopes.py`` reads, on a TPU.
+"""Record a small trace that the benchmark's tests read, on a TPU.
 
-    python3 bench/tests/record_trace.py bench/tests/data/tiny_spans_tpu.xplane.pb.gz
+    python3 bench/tests/record_trace.py bench/tests/data/<name>.xplane.pb.gz
 
-Two FNU rounds of ResNet-4 (3 clients of 20 images, batch 10, fused masked
-Adam, vmap engine) after three set-up rounds, through the benchmark's own
-``harness.drive`` with tracing on, so the trace holds the benchmark's round
-annotations, the program's host spans and its device scopes.  Writes the
-trace gzipped and prints ``bench/scopes.py``'s readings of it.
+The run is described by ``<name>.ctx.json`` beside the output: a test-size
+model (``config``, with its ``reference`` and data ``kind``), its
+``traffic``, the ``chips`` and the ``seed``.  It runs through the
+benchmark's own ``harness.drive`` with tracing on (set-up rounds, then at
+least one whole cycle of the schedule under the profiler), so the trace
+holds the benchmark's round annotations, the program's host spans and its
+device scopes.  Writes the trace gzipped, adds to the ``.ctx.json`` what
+the per-layer metric readers need of the run (``device_kind``,
+``traced_groups``, ``setup_compile_s``), and prints ``bench/scopes.py``'s
+readings of the trace.
+
+``<config>.xplane.pb.gz`` with its ``<config>.ctx.json`` is the pair on
+which ``test_bench_traces.py`` checks every per-layer metric of the
+configuration's cells.
 """
 
 from __future__ import annotations
@@ -19,27 +28,38 @@ from pathlib import Path
 ROOT = Path(__file__).resolve().parents[2]
 sys.path[0] = str(ROOT)
 sys.path.insert(1, str(ROOT / "src"))
+SUFFIX = ".xplane.pb.gz"
 
 
 def main(out: str) -> int:
     import jax
 
     from bench import harness, scopes
-    from bench.tests.test_bench_rehearsal import TINY
 
     if jax.devices()[0].platform != "tpu":
         print(f"no TPU: JAX found {jax.devices()[0].platform}", file=sys.stderr)
         return 2
-    traffic = harness.load_json(ROOT / "bench" / "traffic" / "fedpart-b50-e1-fused.json")
-    traffic.update(schedule="fnu", cohort=3, batch=10, eval_batch=256, trace_rounds=2)
-    cell = harness.Cell("tiny", 1, TINY, traffic, {}, [],
-                        harness.load_module(ROOT, "reference", "resnet"),
-                        harness.load_module(ROOT, "data", "vision"), {})
-    harness.drive(cell, 2**31 + 7, 0.0, trace=True)
+    if not out.endswith(SUFFIX):
+        print(f"the output must end in {SUFFIX}", file=sys.stderr)
+        return 2
+    meta_path = Path(out[: -len(SUFFIX)] + ".ctx.json")
+    meta = harness.load_json(meta_path)
+    cfg = meta["config"]
+    cell = harness.Cell("recorded", meta["chips"], cfg, meta["traffic"], {}, [],
+                        harness.load_module(ROOT, "reference", cfg["reference"]),
+                        harness.load_module(ROOT, "data", cfg["data"]["kind"]), {})
+    clock = harness.CompileClock()
+    record = harness.drive(cell, meta["seed"], 0.0, trace=True)
+    rounds = record["rounds"]
     path = scopes.latest_trace(harness.TRACE_DIR)
     Path(out).write_bytes(gzip.compress(path.read_bytes(), 9))
+    meta.update(device_kind=jax.devices()[0].device_kind,
+                traced_groups=[s.group for s in rounds.window_rounds],
+                setup_compile_s=clock.seconds(0.0, rounds.window_start))
+    meta_path.write_text(json.dumps(meta, indent=1) + "\n")
     scopes.main([str(path)])
-    print(json.dumps({"written": out, "bytes": Path(out).stat().st_size}))
+    print(json.dumps({"written": [out, str(meta_path)],
+                      "bytes": Path(out).stat().st_size}))
     return 0
 
 
