@@ -36,6 +36,7 @@ class ModelConfig:
     vocab_size: int = 1024
     mlp_kind: MlpKind = "swiglu"
     norm_kind: NormKind = "rmsnorm"
+    rms_norm_eps: float = 1e-6
     tie_embeddings: bool = False
     rope_theta: float = 500_000.0
     use_rope: bool = True
@@ -54,6 +55,17 @@ class ModelConfig:
     capacity_factor: float = 1.25
     aux_loss_weight: float = 0.001
     mtp_depth: int = 0               # deepseek-v3 multi-token prediction heads
+    # One chip's share of an expert-parallel layer (``moe.held_moe_apply``):
+    # the router scores all ``num_experts``, this chip holds ``held_experts``
+    # of them from ``held_expert_start`` and computes their part for every
+    # token routed to them, dropless (no capacity, no token dropped).
+    held_experts: int = 0            # 0 = the capacity-bounded moe_apply path
+    held_expert_start: int = 0
+    routed_scale: float = 1.0        # deepseek-v3 routed_scaling_factor
+    # noaux_tc correction bias, added to the scores for selection only:
+    # N(0, router_bias_std) from router_bias_seed, frozen, never trained.
+    router_bias_std: float = 0.0
+    router_bias_seed: int = 0
 
     # MLA (deepseek) -----------------------------------------------------
     use_mla: bool = False
@@ -145,6 +157,7 @@ def _ensure_loaded() -> None:
         llama3_2_1b,
         llama4_maverick_400b_a17b,
         llava_next_34b,
+        moonlight_16b_a3b,
         nlp_transformer,
         resnet,
         tinyllama_1_1b,

@@ -42,12 +42,21 @@ SPANS: dict[str, str] = {
                    "rows the masked-Adam kernel streams per client-step, and "
                    "model_rows, the whole model's)",
     "fl.wait": "the host blocked on a device result (arg what = losses or "
-               "eval)",
+               "eval; a counted adapter's losses add its counter, "
+               "TaskAdapter.counter, e.g. held_assignments: the rows routed "
+               "to the held experts in the round, one number per MoE layer, "
+               "summed over clients and steps, read back with the losses)",
     "fl.eval": "dispatch and readback of the round's eval",
     "grad": "device scope: forward and backward of a local step "
             "(jax.value_and_grad)",
     "masked_adam": "device scope: the Pallas masked-Adam kernel, from its "
                    "pallas_call's name",
+    "mla": "device scope: a packed decoder block's attention, projections "
+           "included (models.attention.mla_packed)",
+    "moe_route": "device scope: a held-expert MoE layer's router, top-k, "
+                 "sort, dispatch and combine (models.moe.held_moe_apply)",
+    "moe_experts": "device scope: the held experts' grouped matmuls "
+                   "(models.moe._grouped_swiglu)",
 }
 
 

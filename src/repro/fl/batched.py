@@ -445,12 +445,14 @@ class _BatchedEngineBase(_CompressionState):
         each leaf's parameter update by its group's plan bit, every step —
         frozen leaves stay re-pinned to the broadcast global.  ``init`` is
         the carry the steps start from when it is not the whole global tree
-        (the fused partial step's, ``LocalTrainer.fused_init``)."""
+        (the fused partial step's, ``LocalTrainer.fused_init``).  Each step
+        returns ``(loss, counters)``; the round returns ``(params, (mean
+        loss, counters summed over the valid steps))``."""
 
         def body(carry, xs):
             params, opt = carry
             x, y, valid = xs
-            new_p, new_o, loss = step_fn(params, opt, x, y, global_params, prev)
+            new_p, new_o, out = step_fn(params, opt, x, y, global_params, prev)
             keep = valid > 0
             if leaf_bits is None:
                 params = jax.tree.map(
@@ -461,14 +463,15 @@ class _BatchedEngineBase(_CompressionState):
                         jnp.logical_and(keep, bit > 0), a, b),
                     new_p, params, leaf_bits)
             opt = jax.tree.map(lambda a, b: jnp.where(keep, a, b), new_o, opt)
-            return (params, opt), jnp.where(keep, loss.astype(jnp.float32), 0.0)
+            return (params, opt), jax.tree.map(
+                lambda v: jnp.where(keep, v.astype(jnp.float32), 0.0), out)
 
         start = global_params if init is None else init
-        (params, _), step_losses = jax.lax.scan(
+        (params, _), (step_losses, counts) = jax.lax.scan(
             body, (start, opt0), (inputs, labels, step_valid)
         )
         mean_loss = jnp.sum(step_losses) / jnp.maximum(jnp.sum(step_valid), 1.0)
-        return params, mean_loss
+        return params, (mean_loss, jnp.sum(counts, axis=0))
 
     def _one_client_fn(self, group: int) -> Callable:
         """Single-client local round (``_scan_local_steps`` over the pruned
@@ -478,7 +481,8 @@ class _BatchedEngineBase(_CompressionState):
         partial round the fused scan carries only the group's trained
         leaves, every layer's BN running moments and Adam state packed for
         the trained leaves; the frozen leaves stay the unbatched global ones
-        and are merged back once, after the scan."""
+        and are merged back once, after the scan.  The loss comes with the
+        steps' counters, ``(loss, counters)`` (``_scan_local_steps``)."""
         if self.fused_adam:
             sel = None if group < 0 else group
             trainer = self.trainer
@@ -487,10 +491,10 @@ class _BatchedEngineBase(_CompressionState):
             def one_client(global_params, inputs, labels, step_valid, prev):
                 carry, opt0 = trainer.fused_init(
                     global_params, sel, FUSED_BLOCK_ROWS)
-                carry, loss = self._scan_local_steps(
+                carry, out = self._scan_local_steps(
                     step_fn, global_params, opt0, inputs, labels, step_valid,
                     prev, init=carry)
-                return masking.tree_update(global_params, carry), loss
+                return masking.tree_update(global_params, carry), out
 
             return one_client
 
@@ -737,7 +741,7 @@ class _BatchedEngineBase(_CompressionState):
                       **self._local_span_args(params, group, plan)):
                 args = self._place_cohort_args(args, submesh,
                                                stacked_prev=use_prev)
-                locals_stacked, bucket_losses = fn(*args)
+                locals_stacked, (bucket_losses, _) = fn(*args)
             n = bucket.num_real
             parts.append((bucket.members, (
                 jax.tree.map(lambda x: x[:n], locals_stacked), bucket_losses[:n],
@@ -779,7 +783,8 @@ class VmapEngine(_BatchedEngineBase):
     def _local_fn(self, group: int, stacked_prev: bool) -> Callable:
         """Jitted vmap-over-clients local round for ``group`` (FULL_NETWORK
         for FNU).  Cached per (group, prev-layout); batch/step widths retrace
-        via jit's shape cache."""
+        via jit's shape cache.  Returns the stacked locals and ``(losses,
+        counters)`` (``_one_client_fn``)."""
         key = (group, stacked_prev)
         if key in self._local_fns:
             return self._local_fns[key]
@@ -965,7 +970,7 @@ class VmapEngine(_BatchedEngineBase):
                         self._bucket_gmask(plan, bucket))
             parts.append((bucket.members, (locals_stacked, bucket_losses)))
 
-        stacked, losses_dev = self._gather_order(parts, num)
+        stacked, (losses_dev, counts_dev) = self._gather_order(parts, num)
         agg_in = stacked                 # MOON keeps the TRUE locals below
         if self.compression is not None:
             res = self._stacked_residuals(ids, range(num), num, params)
@@ -986,8 +991,13 @@ class VmapEngine(_BatchedEngineBase):
                     params, agg_in, jnp.asarray(plan, dtype=jnp.float32),
                     jnp.asarray(weights, dtype=jnp.float32)
                 )
-        with span("fl.wait", what="losses"):
-            losses = [float(x) for x in np.asarray(losses_dev)]
+        counter = self.trainer.adapter.counter
+        with span("fl.wait", what="losses") as wait:
+            losses, counts = jax.device_get((losses_dev, counts_dev))
+            if counter:
+                wait.set_metadata(**{counter: " ".join(
+                    f"{v:.0f}" for v in np.sum(counts, axis=0))})
+            losses = [float(x) for x in losses]
         new_locals = masking.unstack_tree(stacked, num) if use_prev else None
         return new_params, losses, new_locals
 
@@ -1479,7 +1489,7 @@ class ShardMapEngine(_BatchedEngineBase):
                     out = fn(params, bucket.inputs, bucket.labels,
                              bucket.step_valid, prev_arg,
                              self._bucket_gmask(plan, bucket), wb, *res_args)
-            update, bucket_losses = out[0], out[1]
+            update, (bucket_losses, _) = out[0], out[1]
             updates.append(update)
             n = bucket.num_real
             loss_parts.append((bucket.members, bucket_losses[:n]))

@@ -57,7 +57,15 @@ class LocalTrainer:
     # -- loss assembly -----------------------------------------------------
 
     def _total_loss(self, params, inputs, labels, global_params, prev_params):
-        task = self.adapter.loss(params, inputs, labels)
+        """``(loss, counters)``: the task loss with the algorithm's terms, and
+        the adapter's per-step counters from the same forward
+        (``TaskAdapter.counted_loss``; an empty float32 vector for an
+        adapter that counts nothing)."""
+        if self.adapter.counted_loss is None:
+            task = self.adapter.loss(params, inputs, labels)
+            counts = jnp.zeros((0,), jnp.float32)
+        else:
+            task, counts = self.adapter.counted_loss(params, inputs, labels)
         kw: dict = {}
         if self.algo.name == "fedprox":
             kw = {"params": params, "global_params": global_params}
@@ -71,25 +79,28 @@ class LocalTrainer:
                     self.adapter.features(prev_params, inputs)
                 ),
             }
-        return augment_loss(self.algo, task, **kw)
+        return augment_loss(self.algo, task, **kw), counts
 
     # -- step builders -------------------------------------------------------
+    # Every step returns ``(params, opt_state, (loss, counters))``.
 
     def make_full_step(self):
         """Raw (unjitted) FNU step — reused by the batched vmap engine."""
 
         def step(params, opt_state, inputs, labels, global_params, prev_params):
             def loss_fn(p):
-                loss = self._total_loss(p, inputs, labels, global_params, prev_params)
+                loss, counts = self._total_loss(
+                    p, inputs, labels, global_params, prev_params)
                 stats = self.adapter.stats(p, inputs)
-                return loss, stats
+                return loss, (stats, counts)
 
             with jax.named_scope("grad"):
-                (loss, stats), grads = jax.value_and_grad(loss_fn, has_aux=True)(params)
+                (loss, (stats, counts)), grads = jax.value_and_grad(
+                    loss_fn, has_aux=True)(params)
             new_params, new_state = adam_update(grads, opt_state, params, self.adam)
             if stats is not None:
                 new_params = masking.tree_update(new_params, stats)
-            return new_params, new_state, loss
+            return new_params, new_state, (loss, counts)
 
         return step
 
@@ -105,18 +116,19 @@ class LocalTrainer:
 
             def loss_fn(sub):
                 p = masking.merge(sub, frozen)
-                loss = self._total_loss(p, inputs, labels, global_params, prev_params)
+                loss, counts = self._total_loss(
+                    p, inputs, labels, global_params, prev_params)
                 stats = self.adapter.stats(p, inputs)
-                return loss, stats
+                return loss, (stats, counts)
 
             with jax.named_scope("grad"):
-                (loss, stats), grads = jax.value_and_grad(
+                (loss, (stats, counts)), grads = jax.value_and_grad(
                     loss_fn, has_aux=True)(trainable)
             new_sub, new_state = adam_update(grads, opt_state, trainable, self.adam)
             new_params = masking.merge(new_sub, frozen)
             if stats is not None:
                 new_params = masking.tree_update(new_params, stats)
-            return new_params, new_state, loss
+            return new_params, new_state, (loss, counts)
 
         return step
 
@@ -198,13 +210,13 @@ class LocalTrainer:
             def step(params, opt_state, inputs, labels, global_params,
                      prev_params):
                 def loss_fn(p):
-                    loss = self._total_loss(
+                    loss, counts = self._total_loss(
                         p, inputs, labels, global_params, prev_params)
                     stats = self.adapter.stats(p, inputs)
-                    return loss, stats
+                    return loss, (stats, counts)
 
                 with jax.named_scope("grad"):
-                    (loss, stats), grads = jax.value_and_grad(
+                    (loss, (stats, counts)), grads = jax.value_and_grad(
                         loss_fn, has_aux=True)(params)
                 bm = madam_ops.block_mask_for_group(
                     params, partition, tuple(range(partition.num_groups)),
@@ -213,7 +225,7 @@ class LocalTrainer:
                     params, grads, opt_state, bm, block_rows)
                 if stats is not None:
                     new_params = masking.tree_update(new_params, stats)
-                return new_params, new_state, loss
+                return new_params, new_state, (loss, counts)
 
             return step
 
@@ -223,12 +235,13 @@ class LocalTrainer:
 
             def loss_fn(sub):
                 p = masking.merge(sub, stats0, frozen)
-                loss = self._total_loss(p, inputs, labels, global_params, prev_params)
+                loss, counts = self._total_loss(
+                    p, inputs, labels, global_params, prev_params)
                 stats = self.adapter.stats(p, inputs)
-                return loss, stats
+                return loss, (stats, counts)
 
             with jax.named_scope("grad"):
-                (loss, stats), grads = jax.value_and_grad(
+                (loss, (stats, counts)), grads = jax.value_and_grad(
                     loss_fn, has_aux=True)(trained)
             ones = np.ones(madam_ops.packed_rows(trained, block_rows)
                            // block_rows, np.int32)
@@ -237,7 +250,7 @@ class LocalTrainer:
             new_carry = masking.merge(new_sub, stats0)
             if stats is not None:
                 new_carry = masking.tree_update(new_carry, stats)
-            return new_carry, new_state, loss
+            return new_carry, new_state, (loss, counts)
 
         return step
 
@@ -254,12 +267,14 @@ class LocalTrainer:
         def step(params, opt_state, inputs, labels, global_params,
                  prev_params, gmask):
             def loss_fn(p):
-                loss = self._total_loss(p, inputs, labels, global_params, prev_params)
+                loss, counts = self._total_loss(
+                    p, inputs, labels, global_params, prev_params)
                 stats = self.adapter.stats(p, inputs)
-                return loss, stats
+                return loss, (stats, counts)
 
             with jax.named_scope("grad"):
-                (loss, stats), grads = jax.value_and_grad(loss_fn, has_aux=True)(params)
+                (loss, (stats, counts)), grads = jax.value_and_grad(
+                    loss_fn, has_aux=True)(params)
             gids = madam_ops.block_group_ids(
                 params, partition, block_rows,
                 exclude=aggregation.is_local_stat)
@@ -268,7 +283,7 @@ class LocalTrainer:
                 params, grads, opt_state, bm, block_rows)
             if stats is not None:
                 new_params = masking.tree_update(new_params, stats)
-            return new_params, new_state, loss
+            return new_params, new_state, (loss, counts)
 
         return step
 
@@ -348,7 +363,7 @@ class LocalTrainer:
         losses = []
         for inputs, labels in data.batches(batch_size, epochs, seed):
             before = params
-            params, opt_state, loss = step(
+            params, opt_state, (loss, _) = step(
                 params, opt_state, inputs, labels, global_params, prev
             )
             losses.append(float(loss))

@@ -1,5 +1,5 @@
-"""Task adapters: bind a model family (ResNet vision / small-NLP text) to the
-uniform interface the FL engine consumes.
+"""Task adapters: bind a model family (ResNet vision / small-NLP text /
+packed-document decoder) to the uniform interface the FL engine consumes.
 
     adapter.init(key)                       -> params
     adapter.loss(params, inputs, labels)    -> scalar task loss
@@ -7,6 +7,8 @@ uniform interface the FL engine consumes.
     adapter.evaluate(params, inputs, labels)-> accuracy
     adapter.stats(params, inputs)           -> pruned BN-stat updates (or None)
     adapter.partition(params)               -> core.Partition (Appendix-A groups)
+    adapter.counted_loss(params, inputs, labels)
+                                            -> (loss, float32 counters) or None
 """
 
 from __future__ import annotations
@@ -34,6 +36,15 @@ class TaskAdapter:
     stats: Callable[[PyTree, jax.Array], PyTree | None]
     partition: Callable[[PyTree], Partition]
     flops_per_sample: float = 0.0
+    # Counters of what the loss's own forward did, per step: ``counted_loss``
+    # returns the loss and a float32 vector.  Every local step returns it
+    # beside its loss (an empty vector without ``counted_loss``), the local
+    # round sums it over steps, and the vmap engine reads it back with the
+    # losses and sets its sum over clients on the losses' ``fl.wait`` span
+    # as ``counter``.
+    counted_loss: Callable[[PyTree, jax.Array, jax.Array],
+                           tuple[jax.Array, jax.Array]] | None = None
+    counter: str = ""
 
 
 # ---------------------------------------------------------------------------
@@ -174,4 +185,65 @@ def nlp_task(num_classes: int = 4, cfg: ModelConfig | None = None, smoke: bool =
         stats=lambda params, tokens: None,
         partition=make_partition,
         flops_per_sample=float(flops),
+    )
+
+
+# ---------------------------------------------------------------------------
+# Packed-document decoder (next-token fine-tuning, ROADMAP R1)
+# ---------------------------------------------------------------------------
+
+def decoder_task(arch: str = "moonlight-16b-a3b", smoke: bool = False,
+                 bos_id: int = 0) -> TaskAdapter:
+    """Next-token training on rows of packed documents.
+
+    ``inputs`` are token rows (N, S) int32 over the model's vocabulary (a
+    chip's slice of it), each document begun by ``bos_id``; ``labels`` are
+    the next-token targets (N, S), ``bos_id`` where the next token starts
+    another document or lies past the row, and such targets are skipped.
+    ``evaluate`` is the token accuracy over the counted targets.  No batch
+    norm: ``stats`` is None.  The partition is FedPart's per-layer one:
+    embedding; each block; final norm with the head.  ``counted_loss``
+    counts the rows routed to the held experts of each MoE layer
+    (``held_assignments``)."""
+    from repro.models import moe, transformer
+
+    cfg = get_config(arch, smoke=smoke)
+    bias = moe.router_bias(cfg, cfg.num_layers - cfg.first_dense_layers)
+
+    def hidden(params, tokens):
+        return transformer.packed_decoder_hidden(params, cfg, tokens, bias, bos_id)
+
+    def counted_loss(params, tokens, labels):
+        x, held = hidden(params, tokens)
+        logits = x.astype(jnp.float32) @ params["head"]["w"].astype(jnp.float32)
+        return transformer.packed_lm_loss(logits, labels, bos_id), held
+
+    def loss(params, tokens, labels):
+        return counted_loss(params, tokens, labels)[0]
+
+    def features(params, tokens):
+        return jnp.mean(hidden(params, tokens)[0], axis=1)
+
+    def evaluate(params, tokens, labels):
+        def one(row):
+            t, y = row
+            x, _ = hidden(params, t[None])
+            logits = x[0].astype(jnp.float32) @ params["head"]["w"].astype(jnp.float32)
+            counted = y != bos_id
+            return (jnp.sum((jnp.argmax(logits, axis=-1) == y) & counted),
+                    jnp.sum(counted))
+
+        hits, n = jax.lax.map(one, (tokens, labels))
+        return jnp.sum(hits) / jnp.maximum(jnp.sum(n), 1)
+
+    return TaskAdapter(
+        name=cfg.name,
+        init=lambda key: transformer.packed_decoder_init(key, cfg),
+        loss=loss,
+        features=features,
+        evaluate=evaluate,
+        stats=lambda params, tokens: None,
+        partition=build_partition,
+        counted_loss=counted_loss,
+        counter="held_assignments",
     )

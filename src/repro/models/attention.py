@@ -16,6 +16,7 @@ where supported (TPU; interpret mode in tests).
 
 from __future__ import annotations
 
+import functools
 from typing import Any
 
 import jax
@@ -28,6 +29,9 @@ from repro.models.layers import apply_rope, linear, linear_init, rmsnorm, rmsnor
 PyTree = Any
 
 NEG_INF = -1e30
+# Query block of ``mla_packed``: one block's f32 scores, (B, H, 1024, <=S),
+# are live at a time.
+PACKED_Q_BLOCK = 1024
 
 
 # ---------------------------------------------------------------------------
@@ -261,3 +265,55 @@ def mla_decode(
         params, cfg, q, c_cache.astype(x.dtype), r_cache.astype(x.dtype), mask
     )
     return y, (c_cache, r_cache)
+
+
+def _packed_block(q_nope, q_rope, k_nope, k_rope, v, seg_q, seg_k, *, start, scale):
+    """One block of queries (from position ``start``) against the keys up to
+    its last query: causal and within one document."""
+    logits = (
+        jnp.einsum("bqhd,bkhd->bhqk", q_nope, k_nope)
+        + jnp.einsum("bqhd,bkd->bhqk", q_rope, k_rope)
+    ).astype(jnp.float32) * scale
+    qi = start + jnp.arange(q_nope.shape[1])[:, None]
+    kj = jnp.arange(k_nope.shape[1])[None, :]
+    mask = (kj <= qi)[None] & (seg_q[:, :, None] == seg_k[:, None, :])
+    logits = jnp.where(mask[:, None], logits, NEG_INF)
+    probs = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
+    return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+
+
+def mla_packed(
+    params: PyTree,
+    cfg: ModelConfig,
+    x: jax.Array,
+    segment_ids: jax.Array,
+    positions: jax.Array,
+) -> jax.Array:
+    """MLA (no KV cache) over rows of packed documents: a query sees the
+    keys at or before it in its own document (``segment_ids``, (B, S)), and
+    RoPE takes ``positions`` within the document.  Queries run in blocks of
+    ``PACKED_Q_BLOCK``, each against the keys up to its end and each
+    recomputed in the backward, so only one block's scores are ever live."""
+    b, s, _ = x.shape
+    h = cfg.num_heads
+    nope, rope_d, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    q = _mla_q(params, cfg, x)
+    ckr = linear(params["wkv_a"], x)
+    c_kv, k_rope = ckr[..., : cfg.kv_lora_rank], ckr[..., cfg.kv_lora_rank :]
+    cos, sin = rope_angles(positions, rope_d, cfg.rope_theta)
+    q_nope, q_rope = q[..., :nope], apply_rope(q[..., nope:], cos, sin)
+    k_rope = apply_rope(k_rope[..., None, :], cos, sin)[..., 0, :]
+    kv = linear(params["wkv_b"], rmsnorm(params["kv_norm"], c_kv, cfg.rms_norm_eps))
+    kv = kv.reshape(b, s, h, nope + vd)
+    k_nope, v = kv[..., :nope], kv[..., nope:]
+    scale = 1.0 / jnp.sqrt(jnp.float32(nope + rope_d))
+    qb = min(PACKED_Q_BLOCK, s)
+    outs = []
+    for start in range(0, s, qb):
+        end = min(start + qb, s)
+        block = jax.checkpoint(functools.partial(_packed_block, start=start, scale=scale))
+        outs.append(block(q_nope[:, start:end], q_rope[:, start:end],
+                          k_nope[:, :end], k_rope[:, :end], v[:, :end],
+                          segment_ids[:, start:end], segment_ids[:, :end]))
+    y = jnp.concatenate(outs, axis=1) if len(outs) > 1 else outs[0]
+    return linear(params["wo"], y.reshape(b, s, h * vd))

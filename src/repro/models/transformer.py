@@ -11,10 +11,17 @@ Set ``scan_layers=False`` in ``init``/``forward`` calls via config name suffix
 is NOT supported — the FL-simulation models (paper's ResNet / small NLP
 transformer) use the *unstacked* builders in ``repro.models.nlp_small`` and
 ``repro.models.resnet`` instead, which FedPart partitions per-layer.
+
+The packed-document decoder (``packed_decoder_*``, the FL decoder task of
+``fl.tasks.decoder_task``) is the exception: its few layers are held
+unstacked, a dict of blocks run in a Python loop, each recomputed in the
+backward, so that every FedPart group is whole leaves.  Its MoE layers hold
+one chip's share of the experts (``moe.held_moe_apply``).
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Any
 
 import jax
@@ -34,6 +41,8 @@ from repro.models.layers import (
     mlp_init,
     norm_apply,
     norm_init,
+    rmsnorm,
+    rmsnorm_init,
     unembed,
 )
 
@@ -548,6 +557,75 @@ def hybrid_cache_init(cfg: ModelConfig, batch: int, cache_len: int, dtype) -> Py
     cache: PyTree = {"chunks": jax.vmap(one_chunk)(jnp.arange(n_chunks))}
     cache["tail"] = mamba_caches(tail) if tail > 0 else None
     return cache
+
+
+# ---------------------------------------------------------------------------
+# Packed-document decoder, layers unstacked (FedPart's decoder task)
+# ---------------------------------------------------------------------------
+
+def packed_decoder_init(key, cfg: ModelConfig) -> PyTree:
+    """``embed``, ``blocks`` (``"0"`` .. ``str(num_layers - 1)``: MLA, then a
+    dense SwiGLU in the first ``first_dense_layers`` and a held-expert MoE
+    after them), ``final_norm`` and an untied ``head``."""
+    dt, d = _dtype(cfg), cfg.d_model
+    keys = jax.random.split(key, cfg.num_layers + 2)
+    blocks = {}
+    for i in range(cfg.num_layers):
+        k1, k2 = jax.random.split(keys[i])
+        p: PyTree = {"attn_norm": rmsnorm_init(d, dt),
+                     "attn": attn.mla_init(k1, cfg, dt),
+                     "mlp_norm": rmsnorm_init(d, dt)}
+        if i < cfg.first_dense_layers:
+            p["mlp"] = mlp_init(k2, cfg.mlp_kind, d, cfg.d_ff, dt)
+        else:
+            p["moe"] = moe_lib.held_moe_init(k2, cfg, dt)
+        blocks[str(i)] = p
+    return {"embed": embedding_init(keys[-2], cfg.vocab_size, d, dt),
+            "blocks": blocks,
+            "final_norm": rmsnorm_init(d, dt),
+            "head": linear_init(keys[-1], d, cfg.vocab_size, dt)}
+
+
+def _packed_block(p, x, seg, positions, bias, *, cfg):
+    eps = cfg.rms_norm_eps
+    with jax.named_scope("mla"):
+        x = x + attn.mla_packed(p["attn"], cfg, rmsnorm(p["attn_norm"], x, eps),
+                                seg, positions)
+    h = rmsnorm(p["mlp_norm"], x, eps)
+    if "moe" in p:
+        y, held = moe_lib.held_moe_apply(p["moe"], cfg, h, bias)
+    else:
+        y, held = mlp_apply(p["mlp"], cfg.mlp_kind, h), None
+    return x + y, held
+
+
+def packed_decoder_hidden(params: PyTree, cfg: ModelConfig, tokens: jax.Array,
+                          bias: jax.Array, bos_id: int) -> tuple[jax.Array, jax.Array]:
+    """tokens (B, S) of packed documents, each begun by ``bos_id`` ->
+    (final-norm hidden states (B, S, d), rows routed to the held experts per
+    MoE layer (float32, (moe layers,))).  ``bias`` is the MoE layers'
+    ``moe.router_bias``.  Positions restart at each document."""
+    start = tokens == bos_id
+    seg = jnp.cumsum(start, axis=-1)
+    idx = jnp.arange(tokens.shape[-1])
+    positions = idx - jax.lax.cummax(jnp.where(start, idx, 0), axis=tokens.ndim - 1)
+    x = embed(params["embed"], tokens, _act_dtype(cfg))
+    held = []
+    block = jax.checkpoint(functools.partial(_packed_block, cfg=cfg))
+    for i in range(cfg.num_layers):
+        j = i - cfg.first_dense_layers
+        x, n = block(params["blocks"][str(i)], x, seg, positions,
+                     bias[j] if j >= 0 else None)
+        if n is not None:
+            held.append(n)
+    x = rmsnorm(params["final_norm"], x, cfg.rms_norm_eps)
+    return x, jnp.stack(held)
+
+
+def packed_lm_loss(logits: jax.Array, labels: jax.Array, bos_id: int) -> jax.Array:
+    """Next-token cross entropy over the targets that are not a document's
+    first token (``bos_id``, predicted from another document)."""
+    return lm_loss(logits, labels, (labels != bos_id).astype(jnp.float32))
 
 
 # ---------------------------------------------------------------------------

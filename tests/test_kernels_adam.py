@@ -516,7 +516,7 @@ def test_subtree_fused_round_discards_padded_steps(resnet4):
     engine = make_engine("vmap", trainer=trainer, partition=part,
                          algo=AlgoConfig(), fused_adam=True)
     valid = jnp.asarray([[1, 1, 0], [1, 1, 1]], jnp.float32)
-    stacked, losses = engine._local_fn(group, False)(
+    stacked, (losses, _) = engine._local_fn(group, False)(
         params, jnp.stack([xs, xs]), jnp.stack([ys, ys]), valid, params)
 
     step = jax.jit(trainer.make_fused_step(group))

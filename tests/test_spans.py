@@ -124,7 +124,8 @@ def test_every_span_and_scope_in_the_source_is_named_in_spans():
     # the kernel's scope comes from its pallas_call's name
     # (tests/test_tpu_compile.py)
     assert opened == set(SPANS) - {"masked_adam"}
-    assert all(n.startswith("fl.") or n in ("grad", "masked_adam") for n in SPANS)
+    assert all(n.startswith("fl.") or n in ("grad", "masked_adam", "mla", "moe_route",
+                                           "moe_experts") for n in SPANS)
 
 
 def test_a_span_outside_a_profiler_session_records_nothing():

@@ -180,3 +180,35 @@ def test_fused_partial_round_streams_only_the_trained_group(one_chip,
     assert f",{resnet18_rows},{LANES}]" not in partial.as_text()
     assert partial.memory_analysis().temp_size_in_bytes \
         < full.memory_analysis().temp_size_in_bytes
+
+
+def test_held_expert_layer_compiles_at_moonlight_width(one_chip, monkeypatch):
+    """Moonlight's held-expert MoE layer at published widths (d 2,048,
+    expert width 1,408, 8 of 64 experts held, top-6), forward and backward,
+    vmapped over a cohort of 2 with one 4,096-token row each: the grouped
+    matmuls compile as Pallas kernels (three forward; per product, the
+    input and the weight gradient backward) inside the scope the benchmark
+    reads."""
+    import repro.kernels as kernels
+    from repro.configs import get_config
+    from repro.models import moe
+
+    monkeypatch.setattr(kernels, "default_interpret", lambda: False)
+    cfg = get_config("moonlight-16b-a3b")
+    params = jax.eval_shape(lambda k: moe.held_moe_init(k, cfg, jnp.float32),
+                            jax.random.key(0))
+
+    def s(shape, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def loss(p, x, bias):
+        y, held = moe.held_moe_apply(p, cfg, x, bias)
+        return jnp.sum(y * y) + held
+
+    fn = jax.vmap(jax.grad(loss, argnums=(0, 1)), in_axes=(None, 0, None))
+    text = _compile(fn, jax.tree.map(lambda a: s(a.shape), params),
+                    s((2, 1, 4096, cfg.d_model)), s((cfg.num_experts,))).as_text()
+    calls = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(calls) == 9
+    assert all(re.search(r'op_name="[^"]*moe_experts', ln) for ln in calls)
