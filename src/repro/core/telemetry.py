@@ -33,9 +33,17 @@ SPANS: dict[str, str] = {
     "fl.sample": "cohort size, sampling without replacement, per-client "
                  "round seeds",
     "fl.client_data": "the cohort's datasets from the population, their "
-                      "weights, MOON previous models",
-    "fl.stack": "numpy shuffling and stacking of the cohort's batches into "
-                "buckets (args clients, buckets)",
+                      "weights, MOON previous models; in the first round, "
+                      "the copy of a materialised population's data to the "
+                      "engine's device (MaterializedPopulation.device_mirror; "
+                      "args mirror_bytes, mirror_clients when it is made)",
+    "fl.stack": "the cohort's batch order into buckets (args clients, "
+                "buckets, source, h2d_bytes): with source=device, index "
+                "arrays and the dispatch of their gather from the device "
+                "mirror; with source=host, numpy stacking of the batches, "
+                "which the local round then copies to the device; h2d_bytes "
+                "is what the round's batches send host to device (indices, "
+                "or inputs and labels)",
     "fl.dispatch": "one call into a jitted program: argument handling, "
                    "host-to-device copies, enqueue (arg program = local, tx "
                    "or agg; a fused local round adds kernel_rows, the packed "

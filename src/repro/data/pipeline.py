@@ -97,6 +97,65 @@ class StackedClientBatches:
         return self.inputs.shape[2]
 
 
+@dataclasses.dataclass
+class BucketPlan:
+    """One bucket's batch order before any data is read.
+
+    ``rows[c, t]`` are the indices into the dataset of round position
+    ``owners[c]`` that client row ``c`` trains on at step ``t``: the
+    ``batch_plan`` rows, ragged step counts padded with the first batch.
+    Padding clients (rows ``>= len(members)``) repeat the first member.
+    """
+
+    members: tuple[int, ...]
+    owners: tuple[int, ...]
+    rows: np.ndarray          # (clients, steps, bs) int64
+    step_valid: np.ndarray    # (clients, steps) float32
+
+
+def plan_buckets(
+    sizes: Sequence[int],
+    batch_size: int,
+    epochs: int,
+    seeds: Sequence[int],
+    *,
+    pad_clients_to: int = 1,
+) -> list[BucketPlan]:
+    """The bucketing of ``stack_client_batches`` from the clients' sizes
+    alone: one ``BucketPlan`` per effective batch width, in ascending
+    width order."""
+    if len(sizes) != len(seeds):
+        raise ValueError("one seed per client dataset is required")
+    if pad_clients_to < 1:
+        raise ValueError(f"pad_clients_to must be >= 1, got {pad_clients_to}")
+    buckets: dict[int, list[int]] = {}
+    for pos, n in enumerate(sizes):
+        buckets.setdefault(min(batch_size, n), []).append(pos)
+
+    out = []
+    for bs in sorted(buckets):
+        members = buckets[bs]
+        plans = [batch_plan(sizes[p], batch_size, epochs, seeds[p])
+                 for p in members]
+        max_steps = max(len(pl) for pl in plans)
+        rows, valid = [], []
+        for plan in plans:
+            pad = max_steps - len(plan)
+            if pad:
+                plan = np.concatenate([plan, np.repeat(plan[:1], pad, axis=0)])
+            rows.append(plan)
+            v = np.zeros(max_steps, dtype=np.float32)
+            v[: max_steps - pad] = 1.0
+            valid.append(v)
+        n_pad = -len(members) % pad_clients_to
+        rows += [rows[0]] * n_pad
+        valid += [np.zeros(max_steps, dtype=np.float32)] * n_pad
+        out.append(BucketPlan(
+            members=tuple(members), owners=tuple(members + members[:1] * n_pad),
+            rows=np.stack(rows), step_valid=np.stack(valid)))
+    return out
+
+
 def stack_client_batches(
     datasets: Sequence[ClientDataset],
     batch_size: int,
@@ -118,40 +177,12 @@ def stack_client_batches(
     zero aggregation weight, so results are unchanged (see
     ``StackedClientBatches``).
     """
-    if len(datasets) != len(seeds):
-        raise ValueError("one seed per client dataset is required")
-    if pad_clients_to < 1:
-        raise ValueError(f"pad_clients_to must be >= 1, got {pad_clients_to}")
-    buckets: dict[int, list[int]] = {}
-    for pos, ds in enumerate(datasets):
-        buckets.setdefault(min(batch_size, len(ds)), []).append(pos)
-
-    out = []
-    for bs in sorted(buckets):
-        members = buckets[bs]
-        plans = [batch_plan(len(datasets[p]), batch_size, epochs, seeds[p])
-                 for p in members]
-        max_steps = max(len(pl) for pl in plans)
-        xs, ys, valid = [], [], []
-        for p, plan in zip(members, plans):
-            pad = max_steps - len(plan)
-            if pad:
-                plan = np.concatenate([plan, np.repeat(plan[:1], pad, axis=0)])
-            xs.append(datasets[p].inputs[plan])
-            ys.append(datasets[p].labels[plan])
-            v = np.zeros(max_steps, dtype=np.float32)
-            v[: max_steps - pad] = 1.0
-            valid.append(v)
-        n_pad = -len(members) % pad_clients_to
-        for _ in range(n_pad):
-            xs.append(xs[0])
-            ys.append(ys[0])
-            valid.append(np.zeros(max_steps, dtype=np.float32))
-        out.append(StackedClientBatches(
-            inputs=np.stack(xs), labels=np.stack(ys),
-            step_valid=np.stack(valid), members=tuple(members),
-        ))
-    return out
+    plans = plan_buckets([len(d) for d in datasets], batch_size, epochs,
+                         seeds, pad_clients_to=pad_clients_to)
+    return [StackedClientBatches(
+        inputs=np.stack([datasets[o].inputs[r] for o, r in zip(b.owners, b.rows)]),
+        labels=np.stack([datasets[o].labels[r] for o, r in zip(b.owners, b.rows)]),
+        step_valid=b.step_valid, members=b.members) for b in plans]
 
 
 def build_clients(

@@ -7,10 +7,11 @@ handful of compiled dispatches per (phase, group), sharing one *pad-and-mask
 local-round core* (``_BatchedEngineBase``):
 
 * ``VmapEngine`` — the selected clients' batches are stacked along a leading
-  client axis (``data.pipeline.stack_client_batches``) and the whole local
-  round runs as one ``jax.vmap``-over-clients program with a ``lax.scan`` over
-  steps inside, followed by one on-device stacked aggregation
-  (``core.aggregation.*_stacked``).  Single device.
+  client axis (``data.pipeline.stack_client_batches``, or gathered by index
+  from a population's ``DeviceMirror`` already on the device) and the whole
+  local round runs as one ``jax.vmap``-over-clients program with a
+  ``lax.scan`` over steps inside, followed by one on-device stacked
+  aggregation (``core.aggregation.*_stacked``).  Single device.
 * ``ShardMapEngine`` — the stacked client axis is distributed over a 1-D
   ``jax.sharding.Mesh`` ("clients" axis, ``launch.mesh.make_client_mesh``)
   via ``shard_map``: each device vmaps the local round over its shard of
@@ -231,6 +232,8 @@ class SequentialEngine(_CompressionState):
     state_store: Any = None     # fl.population.ClientStateStore (EF residuals)
     name: str = "sequential"
 
+    mirror_device = None        # batches come from the datasets, per client
+
     def __post_init__(self):
         self._init_compression_state()
         if self.fused_adam:
@@ -372,7 +375,8 @@ class _BatchedEngineBase(_CompressionState):
       over a client axis;
     * the bucketed batch plan (``_buckets``): one
       ``data.pipeline.stack_client_batches`` bucket per effective batch
-      width, with the MOON prev-model stacking and padding-client handling;
+      width (or its gather from a ``DeviceMirror``), with the MOON
+      prev-model stacking and padding-client handling;
     * ``_gather_order`` — concatenating per-bucket per-client outputs back
       into the round's picked-client order.
 
@@ -389,8 +393,13 @@ class _BatchedEngineBase(_CompressionState):
     compression: compress.CompressionConfig | None = None
     state_store: Any = None     # fl.population.ClientStateStore (EF residuals)
 
+    # The device a population's ``DeviceMirror`` should live on for this
+    # engine's ``run_round``; ``None`` keeps the host path.
+    mirror_device = None
+
     def __post_init__(self):
         self.trace_count = 0
+        self.stack_source: str | None = None   # "device" or "host", last round
         self._local_fns: dict[tuple[int, bool], Callable] = {}
         self._agg_fns: dict[Any, Callable] = {}
         self._cohort_fns: dict[tuple[int, bool], Callable] = {}
@@ -623,16 +632,34 @@ class _BatchedEngineBase(_CompressionState):
         prev_params: Sequence[PyTree | None] | None,
         use_prev: bool,
         pad_clients_to: int = 1,
+        mirror=None,
+        client_ids: Sequence[int] | None = None,
     ):
         """Yield ``(bucket, prev_arg)`` per batch-width bucket.  ``prev_arg``
         is the MOON previous-local-model argument: stacked per client (padding
         clients fall back to the global model) when ``use_prev``, else the
-        global params broadcast unbatched."""
+        global params broadcast unbatched.
+
+        With a ``mirror`` (``fl.population.DeviceMirror``) the buckets are
+        gathered on its device from the cohort's ``client_ids``; otherwise
+        they are stacked on the host.  ``stack_source`` records which."""
         with span("fl.stack", clients=len(datasets)) as stack:
-            buckets = stack_client_batches(
-                datasets, batch_size, epochs, seeds,
-                pad_clients_to=pad_clients_to)
-            stack.set_metadata(buckets=len(buckets))
+            if mirror is None:
+                buckets = stack_client_batches(
+                    datasets, batch_size, epochs, seeds,
+                    pad_clients_to=pad_clients_to)
+                h2d = sum(b.inputs.nbytes + b.labels.nbytes for b in buckets)
+                self.stack_source = "host"
+            else:
+                if client_ids is None or len(client_ids) != len(datasets):
+                    raise ValueError("a device mirror needs one client id "
+                                     "per client dataset")
+                buckets, h2d = mirror.stack_client_batches(
+                    client_ids, batch_size, epochs, seeds,
+                    pad_clients_to=pad_clients_to)
+                self.stack_source = "device"
+            stack.set_metadata(buckets=len(buckets), source=self.stack_source,
+                               h2d_bytes=h2d)
         for bucket in buckets:
             if use_prev:
                 prevs = [
@@ -777,6 +804,12 @@ class VmapEngine(_BatchedEngineBase):
     """Batched engine: whole round = vmapped local training + on-device agg."""
 
     name: str = "vmap"
+
+    @property
+    def mirror_device(self):
+        """``run_round`` gathers from a mirror on JAX's default device, where
+        its programs run."""
+        return jax.devices()[0]
 
     # -- compiled-program builders ----------------------------------------
 
@@ -942,7 +975,12 @@ class VmapEngine(_BatchedEngineBase):
         tracker=None,
         plan=None,
         client_ids: Sequence[int] | None = None,
+        mirror=None,
     ) -> tuple[PyTree, list[float], list[PyTree] | None]:
+        """One synchronous round.  With a ``mirror`` (the population's
+        ``DeviceMirror``) the cohort's batches are gathered on the device by
+        ``client_ids`` instead of stacked on the host; the local-round
+        program gets the same values, shapes and dtypes either way."""
         self._guard_round(weights, tracker)
         plan = resolve_plan(plan, spec, self.partition.num_groups)
         ids = self._require_client_ids(client_ids, len(datasets))
@@ -953,7 +991,8 @@ class VmapEngine(_BatchedEngineBase):
         parts: list[tuple[tuple[int, ...], tuple[PyTree, jax.Array]]] = []
         for bucket, prev_arg in self._buckets(
             params, datasets, batch_size=batch_size, epochs=epochs, seeds=seeds,
-            prev_params=prev_params, use_prev=use_prev,
+            prev_params=prev_params, use_prev=use_prev, mirror=mirror,
+            client_ids=client_ids,
         ):
             with span("fl.dispatch", program="local",
                       **self._local_span_args(params, group, plan)):

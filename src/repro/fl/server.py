@@ -267,7 +267,8 @@ def run_federated(
     # streaming population of millions costs O(cohort) per round.
     population = as_population(clients_data)
     n_clients = population.num_clients
-    for spec in rounds:
+    mirror_kw: dict = {}
+    for i, spec in enumerate(rounds):
         with span("fl.round", round=spec.index, group=spec.group,
                   phase=spec.phase):
             t_round = time.perf_counter()
@@ -280,7 +281,18 @@ def run_federated(
             if tracker is not None:
                 tracker.mark_round_boundary()
 
-            with span("fl.client_data"):
+            with span("fl.client_data") as client_data:
+                if i == 0:
+                    # A materialised population that fits is copied once to
+                    # the engine's device, and every round's batches are
+                    # gathered there by index; otherwise they are stacked on
+                    # the host each round.
+                    mirror = population.device_mirror(engine.mirror_device)
+                    if mirror is not None:
+                        mirror_kw = {"mirror": mirror}
+                        client_data.set_metadata(
+                            mirror_bytes=mirror.nbytes,
+                            mirror_clients=mirror.num_clients)
                 datasets = [population.dataset(ci) for ci in picked]
                 weights = [len(d) for d in datasets]
                 prevs = ([state_store.get("moon", int(ci)) for ci in picked]
@@ -298,6 +310,7 @@ def run_federated(
                 tracker=tracker,
                 plan=assigner.assign(spec, [int(ci) for ci in picked]),
                 client_ids=[int(ci) for ci in picked],
+                **mirror_kw,
             )
             if new_locals is not None:
                 for ci, local in zip(picked, new_locals):

@@ -81,7 +81,13 @@ def test_span_arguments(traced_rounds):
     args = {}
     for _, name, _, _, st in events:
         args.setdefault(name, []).append(st)
-    assert all(st == {"clients": 2, "buckets": 1} for st in args["fl.stack"])
+    # the materialised clients are copied to the device in the first round;
+    # each round then sends only its batches' indices: 2 clients x 2 steps
+    # x 12 int32
+    assert args["fl.client_data"] == [
+        {"mirror_bytes": 48 * (8 * 8 * 3 + 1) * 4, "mirror_clients": 2}, {}]
+    assert all(st == {"clients": 2, "buckets": 1, "source": "device",
+                      "h2d_bytes": 2 * 2 * 12 * 4} for st in args["fl.stack"])
     assert {st["program"] for st in args["fl.dispatch"]} == {"local", "agg"}
     assert sorted(st["what"] for st in args["fl.wait"]) == [
         "eval", "eval", "losses", "losses"]
