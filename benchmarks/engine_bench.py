@@ -104,10 +104,9 @@ def _time_engine(engine_name, adapter, data, params, partition, spec,
     shapes every round — no retraces, same per-round work either way)."""
     algo = AlgoConfig()
     trainer = LocalTrainer(adapter=adapter, partition=partition, algo=algo,
-                           adam=AdamConfig(lr=1e-3))
+                           adam=AdamConfig(lr=1e-3), fused=fused)
     engine = make_engine(engine_name, trainer=trainer, partition=partition,
-                         algo=algo, sim_devices=sim_devices, donate=donate,
-                         fused_adam=fused)
+                         algo=algo, sim_devices=sim_devices, donate=donate)
     seeds = list(range(len(data)))
     weights = [len(d) for d in data]
     import jax.numpy as jnp

@@ -313,9 +313,9 @@ def four_chip_phase(job: Job, chips: int = 4) -> dict:
     params = adapter.init(jax.random.key(job.seed))
     partition = adapter.partition(params)
     trainer = LocalTrainer(adapter=adapter, partition=partition,
-                           algo=AlgoConfig(), adam=AdamConfig())
+                           algo=AlgoConfig(), adam=AdamConfig(), fused=True)
     engine = make_engine("shard_map", trainer=trainer, partition=partition,
-                         algo=AlgoConfig(), sim_devices=chips, fused_adam=True)
+                         algo=AlgoConfig(), sim_devices=chips)
     stacked, losses = engine.run_local_async(
         params, rounds[1], clients, seeds=list(range(len(clients))),
         epochs=job.local_epochs, batch_size=job.batch_size)

@@ -112,46 +112,12 @@ from repro.core.telemetry import span
 from repro.data.pipeline import ClientDataset, stack_client_batches
 from repro.fl.algorithms import AlgoConfig
 from repro.fl.client import LocalTrainer
-from repro.kernels.masked_adam import ops as madam_ops
-from repro.optim.adam import adam_init
-from repro.optim.partial import fused_adam_init, guard_fused_config
 
 PyTree = Any
 
 ENGINES = ("sequential", "vmap", "shard_map")
 
 CLIENT_AXIS = "clients"  # mesh axis name the shard_map engine reduces over
-
-FUSED_BLOCK_ROWS = 8     # kernel block granularity the fused engines pack to
-
-
-def _transmitted_rows(params: PyTree, partition: Partition, groups,
-                      block_rows: int = FUSED_BLOCK_ROWS) -> np.ndarray:
-    """Static packed-row indices of the round's *transmitted* blocks: the
-    trainable ``groups``' leaves minus BN running moments — exactly the
-    subtree the unfused shard_map path selects + ``drop_local_stats``-es
-    before its psum, expressed in ``ops.pack`` layout."""
-    bm = madam_ops.block_mask_for_group(
-        params, partition, groups, block_rows,
-        exclude=aggregation.is_local_stat)
-    blocks = np.flatnonzero(bm)
-    return (blocks[:, None] * block_rows
-            + np.arange(block_rows)[None, :]).reshape(-1)
-
-
-def _plan_rows(params: PyTree, partition: Partition,
-               block_rows: int = FUSED_BLOCK_ROWS
-               ) -> tuple[np.ndarray, np.ndarray]:
-    """Static (rows, per-row group ids) for plan rounds: every non-stat
-    block travels (any client may have trained it), each row weighted by its
-    group's per-client effective weight."""
-    gids = madam_ops.block_group_ids(
-        params, partition, block_rows, exclude=aggregation.is_local_stat)
-    blocks = np.flatnonzero(gids >= 0)
-    rows = (blocks[:, None] * block_rows
-            + np.arange(block_rows)[None, :]).reshape(-1)
-    return rows, np.repeat(gids[blocks], block_rows)
-
 
 def resolve_plan(plan, spec: RoundSpec, num_groups: int):
     """Normalise a per-client layer plan (``core.schedule.PlanAssigner``).
@@ -227,7 +193,6 @@ class SequentialEngine(_CompressionState):
     trainer: LocalTrainer
     partition: Partition
     algo: AlgoConfig
-    fused_adam: bool = False
     compression: compress.CompressionConfig | None = None
     state_store: Any = None     # fl.population.ClientStateStore (EF residuals)
     name: str = "sequential"
@@ -236,8 +201,6 @@ class SequentialEngine(_CompressionState):
 
     def __post_init__(self):
         self._init_compression_state()
-        if self.fused_adam:
-            guard_fused_config(self.trainer.adam)
 
     @property
     def trace_count(self) -> int:
@@ -275,7 +238,6 @@ class SequentialEngine(_CompressionState):
                 prev_params=prev_params[i] if prev_params is not None else None,
                 step_tracker=tracker if i == 0 else None,
                 groups=groups_i,
-                fused=self.fused_adam,
             )
             losses.append(loss)
             if keep_locals:
@@ -330,7 +292,6 @@ class SequentialEngine(_CompressionState):
                 prev_params=prev_params[i] if prev_params is not None else None,
                 groups=(tuple(int(g) for g in np.flatnonzero(plan[i]))
                         if plan is not None else None),
-                fused=self.fused_adam,
             )
             locals_.append(local)
             losses.append(loss)
@@ -389,7 +350,6 @@ class _BatchedEngineBase(_CompressionState):
     partition: Partition
     algo: AlgoConfig
     donate: bool = True
-    fused_adam: bool = False
     compression: compress.CompressionConfig | None = None
     state_store: Any = None     # fl.population.ClientStateStore (EF residuals)
 
@@ -405,8 +365,6 @@ class _BatchedEngineBase(_CompressionState):
         self._cohort_fns: dict[tuple[int, bool], Callable] = {}
         self._kernel_rows: dict[int | None, dict[str, int]] = {}
         self._init_compression_state()
-        if self.fused_adam:
-            guard_fused_config(self.trainer.adam)
 
     # Donation sets (active when ``donate``).  Only buffers whose shapes can
     # actually alias an output are donated — donating the stacked
@@ -433,29 +391,24 @@ class _BatchedEngineBase(_CompressionState):
         the whole model's (``model_rows``), from shapes alone, once per
         program.  A partial round streams its group's trained rows; FNU and
         plan rounds stream the whole model."""
-        if not self.fused_adam:
+        if not self.trainer.fused:
             return {}
         sel = None if plan is not None or group < 0 else group
         if sel not in self._kernel_rows:
             self._kernel_rows[sel] = {
-                "kernel_rows": self.trainer.fused_kernel_rows(
-                    params, sel, FUSED_BLOCK_ROWS),
-                "model_rows": madam_ops.packed_rows(params, FUSED_BLOCK_ROWS)}
+                "kernel_rows": self.trainer.kernel_rows(params, sel),
+                "model_rows": self.trainer.kernel_rows(params)}
         return self._kernel_rows[sel]
 
     # -- shared local-round core -------------------------------------------
 
     @staticmethod
-    def _scan_local_steps(step_fn, global_params, opt0, inputs, labels,
-                          step_valid, prev, leaf_bits=None, init=None):
-        """The shared pad-and-mask scan over (possibly padded) steps: invalid
-        steps compute but their parameter/optimizer updates and losses are
-        discarded.  ``leaf_bits`` (per-client layer plans) additionally masks
-        each leaf's parameter update by its group's plan bit, every step —
-        frozen leaves stay re-pinned to the broadcast global.  ``init`` is
-        the carry the steps start from when it is not the whole global tree
-        (the fused partial step's, ``LocalTrainer.fused_init``).  Each step
-        returns ``(loss, counters)``; the round returns ``(params, (mean
+    def _scan_local_steps(step_fn, carry0, opt0, global_params, inputs,
+                          labels, step_valid, prev):
+        """The shared pad-and-mask scan over (possibly padded) steps from
+        the carry ``carry0`` (``LocalTrainer.init``): invalid steps compute
+        but their carry/optimizer updates and losses are discarded.  Each
+        step returns ``(loss, counters)``; the round returns ``(carry, (mean
         loss, counters summed over the valid steps))``."""
 
         def body(carry, xs):
@@ -463,64 +416,36 @@ class _BatchedEngineBase(_CompressionState):
             x, y, valid = xs
             new_p, new_o, out = step_fn(params, opt, x, y, global_params, prev)
             keep = valid > 0
-            if leaf_bits is None:
-                params = jax.tree.map(
-                    lambda a, b: jnp.where(keep, a, b), new_p, params)
-            else:
-                params = jax.tree.map(
-                    lambda a, b, bit: jnp.where(
-                        jnp.logical_and(keep, bit > 0), a, b),
-                    new_p, params, leaf_bits)
+            params = jax.tree.map(
+                lambda a, b: jnp.where(keep, a, b), new_p, params)
             opt = jax.tree.map(lambda a, b: jnp.where(keep, a, b), new_o, opt)
             return (params, opt), jax.tree.map(
                 lambda v: jnp.where(keep, v.astype(jnp.float32), 0.0), out)
 
-        start = global_params if init is None else init
         (params, _), (step_losses, counts) = jax.lax.scan(
-            body, (start, opt0), (inputs, labels, step_valid)
+            body, (carry0, opt0), (inputs, labels, step_valid)
         )
         mean_loss = jnp.sum(step_losses) / jnp.maximum(jnp.sum(step_valid), 1.0)
         return params, (mean_loss, jnp.sum(counts, axis=0))
 
     def _one_client_fn(self, group: int) -> Callable:
-        """Single-client local round (``_scan_local_steps`` over the pruned
-        full/partial step for ``group``).  With ``fused_adam`` the step is
-        the Pallas masked-Adam kernel over the packed (rows, 128) layout
-        instead, with packed optimizer state (docs/KERNELS.md).  On a
-        partial round the fused scan carries only the group's trained
-        leaves, every layer's BN running moments and Adam state packed for
-        the trained leaves; the frozen leaves stay the unbatched global ones
-        and are merged back once, after the scan.  The loss comes with the
-        steps' counters, ``(loss, counters)`` (``_scan_local_steps``)."""
-        if self.fused_adam:
-            sel = None if group < 0 else group
-            trainer = self.trainer
-            step_fn = trainer.make_fused_step(sel, FUSED_BLOCK_ROWS)
-
-            def one_client(global_params, inputs, labels, step_valid, prev):
-                carry, opt0 = trainer.fused_init(
-                    global_params, sel, FUSED_BLOCK_ROWS)
-                carry, out = self._scan_local_steps(
-                    step_fn, global_params, opt0, inputs, labels, step_valid,
-                    prev, init=carry)
-                return masking.tree_update(global_params, carry), out
-
-            return one_client
-
-        step_fn = (
-            self.trainer.make_full_step()
-            if group < 0
-            else self.trainer.make_partial_step(group)
-        )
-        partition = self.partition
+        """Single-client local round: ``_scan_local_steps`` over the
+        trainer's step for ``group`` (FULL_NETWORK for FNU).  On a partial
+        round the scan carries only the group's trained leaves, every
+        layer's BN running moments and Adam state for the trained leaves
+        (``LocalTrainer.init``); the frozen leaves stay the unbatched global
+        ones and are merged back once, after the scan.  The loss comes with
+        the steps' counters, ``(loss, counters)``."""
+        trainer = self.trainer
+        sel = None if group < 0 else group
+        step_fn = trainer.make_step(sel)
 
         def one_client(global_params, inputs, labels, step_valid, prev):
-            if group < 0:
-                opt0 = adam_init(global_params)
-            else:
-                opt0 = adam_init(masking.select(global_params, partition, group))
-            return self._scan_local_steps(
-                step_fn, global_params, opt0, inputs, labels, step_valid, prev)
+            carry, opt0 = trainer.init(global_params, sel)
+            carry, out = self._scan_local_steps(
+                step_fn, carry, opt0, global_params, inputs, labels,
+                step_valid, prev)
+            return masking.tree_update(global_params, carry), out
 
         return one_client
 
@@ -528,53 +453,29 @@ class _BatchedEngineBase(_CompressionState):
         """Single-client local round under a per-client layer plan.
 
         The FNU step runs every group's arithmetic and the client's ``(M,)``
-        group bitmask masks the parameter update per leaf, each step — the
-        paper's Eq. 1 literal masked form.  That is what lets ONE compiled
-        program serve every plan row in a stacked cohort: the pruned-subtree
-        form the homogeneous paths run would need one trace per distinct
-        group set, defeating vmap/shard_map.  Frozen leaves are re-pinned to
-        the broadcast global after every step, so trainable leaves see
-        exactly the frozen context the pruned form sees (equivalence to the
-        sequential oracle pinned in tests/test_engine_equivalence.py).
-        Client-local statistics (BN running moments) always update,
-        mirroring the pruned path's stats splice.
-
-        With ``fused_adam`` the per-client bitmask instead becomes a traced
-        per-*block* kernel mask (``ops.plan_block_mask``): untrained blocks
-        are frozen inside the kernel itself, so no per-leaf re-pinning is
-        needed — still one compiled program for every plan row."""
-        if self.fused_adam:
-            plan_step = self.trainer.make_fused_plan_step(FUSED_BLOCK_ROWS)
-
-            def one_client(global_params, inputs, labels, step_valid, prev,
-                           gmask):
-                opt0 = fused_adam_init(global_params, FUSED_BLOCK_ROWS)
-
-                def step_fn(p, o, x, y, gp, pv):
-                    return plan_step(p, o, x, y, gp, pv, gmask)
-
-                return self._scan_local_steps(
-                    step_fn, global_params, opt0, inputs, labels, step_valid,
-                    prev)
-
-            return one_client
-
-        step_fn = self.trainer.make_full_step()
-        partition = self.partition
+        group bitmask masks the update inside the step, per leaf (tree Adam)
+        or per packed block (the masked-Adam kernel) — the paper's Eq. 1
+        literal masked form.  That is what lets ONE compiled program serve
+        every plan row in a stacked cohort: the pruned-subtree form the
+        homogeneous paths run would need one trace per distinct group set,
+        defeating vmap/shard_map.  Frozen leaves keep the broadcast global
+        values every step, so trainable leaves see exactly the frozen
+        context the pruned form sees (equivalence to the sequential oracle
+        pinned in tests/test_engine_equivalence.py).  Client-local
+        statistics (BN running moments) always update, mirroring the pruned
+        path's stats splice."""
+        trainer = self.trainer
+        plan_step = trainer.make_step(None)
 
         def one_client(global_params, inputs, labels, step_valid, prev, gmask):
-            opt0 = adam_init(global_params)
+            carry, opt0 = trainer.init(global_params)
 
-            def _bit(path, leaf):
-                p = "/".join(masking._entry_str(e) for e in path)
-                if aggregation.is_local_stat(p):
-                    return jnp.float32(1.0)      # stats ride along unmasked
-                return gmask[partition.group_of(p)]
+            def step_fn(p, o, x, y, gp, pv):
+                return plan_step(p, o, x, y, gp, pv, gmask)
 
-            leaf_bits = jax.tree_util.tree_map_with_path(_bit, global_params)
             return self._scan_local_steps(
-                step_fn, global_params, opt0, inputs, labels, step_valid,
-                prev, leaf_bits=leaf_bits)
+                step_fn, carry, opt0, global_params, inputs, labels,
+                step_valid, prev)
 
         return one_client
 
@@ -1088,18 +989,13 @@ class ShardMapEngine(_BatchedEngineBase):
         one_client = self._one_client_fn(group)
         partition = self.partition
         prev_axis = 0 if stacked_prev else None
-
-        fused = self.fused_adam
         cfg = self.compression
 
         if cfg is not None:
             # Compressed transmission boundary: each device quantizes its
             # clients' updates (with per-client error-feedback residuals
             # riding the client axis) BEFORE the weight-scale psum, so only
-            # compressed-value subtrees ever cross the mesh.  The epilogue is
-            # always the per-leaf tree form — the fused packed epilogue stays
-            # reserved for the uncompressed path (training steps may still
-            # run the fused kernel; only the reduction differs).
+            # compressed-value subtrees ever cross the mesh.
             sel = None if group < 0 else (group,)
 
             def device_round(global_params, inputs, labels, step_valid, prev,
@@ -1144,26 +1040,14 @@ class ShardMapEngine(_BatchedEngineBase):
             locals_stacked, losses = jax.vmap(
                 one_client, in_axes=(None, 0, 0, 0, prev_axis)
             )(global_params, inputs, labels, step_valid, prev)
-            if fused:
-                # Fused weight-scale epilogue: pack the stacked locals back
-                # into kernel layout and reduce only the *transmitted* rows
-                # (trainable groups minus BN stats) — one gather + tensordot
-                # instead of a per-leaf tree, and only scaled transmitted
-                # blocks ever leave the device.
-                packed, _ = madam_ops.pack_stacked(
-                    locals_stacked, FUSED_BLOCK_ROWS)
-                sel = tuple(range(partition.num_groups)) if group < 0 else group
-                tx = _transmitted_rows(global_params, partition, sel)
-                update = jnp.tensordot(w_norm, packed[:, tx], axes=1)
-            else:
-                sub = (
-                    locals_stacked if group < 0
-                    else masking.select(locals_stacked, partition, group)
-                )
-                sub = aggregation.drop_local_stats(sub)
-                update = jax.tree.map(
-                    lambda x: jnp.tensordot(w_norm, x.astype(jnp.float32), axes=1), sub
-                )
+            sub = (
+                locals_stacked if group < 0
+                else masking.select(locals_stacked, partition, group)
+            )
+            sub = aggregation.drop_local_stats(sub)
+            update = jax.tree.map(
+                lambda x: jnp.tensordot(w_norm, x.astype(jnp.float32), axes=1), sub
+            )
             update = jax.lax.psum(update, CLIENT_AXIS)
             if stacked_prev:
                 return update, losses, locals_stacked
@@ -1197,14 +1081,12 @@ class ShardMapEngine(_BatchedEngineBase):
         one_client = self._one_client_plan_fn()
         partition = self.partition
         prev_axis = 0 if stacked_prev else None
-
-        fused = self.fused_adam
         cfg = self.compression
 
         if cfg is not None:
             # Compressed plan boundary: per-client traced bitmask decides
             # which leaves consume error feedback and travel; the per-leaf
-            # plan-weighted psum epilogue follows (tree form — see _local_fn).
+            # plan-weighted psum epilogue follows.
             def device_round(global_params, inputs, labels, step_valid, prev,
                              gmask, eff_w, res):
                 self.trace_count += 1
@@ -1247,25 +1129,14 @@ class ShardMapEngine(_BatchedEngineBase):
             locals_stacked, losses = jax.vmap(
                 one_client, in_axes=(None, 0, 0, 0, prev_axis, 0)
             )(global_params, inputs, labels, step_valid, prev, gmask)
-            if fused:
-                # Fused plan epilogue: every non-stat row travels (any client
-                # may have trained it), weighted per row by its group's
-                # per-client effective weight — one einsum over the packed
-                # buffer instead of a per-leaf tree walk.
-                packed, _ = madam_ops.pack_stacked(
-                    locals_stacked, FUSED_BLOCK_ROWS)
-                rows, gids_rows = _plan_rows(global_params, partition)
-                wrow = eff_w[:, gids_rows]                     # (C, T)
-                update = jnp.einsum("ct,ctl->tl", wrow, packed[:, rows])
-            else:
-                sub = aggregation.drop_local_stats(locals_stacked)
+            sub = aggregation.drop_local_stats(locals_stacked)
 
-                def _wsum(path, x):
-                    g = partition.group_of(
-                        "/".join(masking._entry_str(e) for e in path))
-                    return jnp.tensordot(eff_w[:, g], x.astype(jnp.float32), axes=1)
+            def _wsum(path, x):
+                g = partition.group_of(
+                    "/".join(masking._entry_str(e) for e in path))
+                return jnp.tensordot(eff_w[:, g], x.astype(jnp.float32), axes=1)
 
-                update = jax.tree_util.tree_map_with_path(_wsum, sub)
+            update = jax.tree_util.tree_map_with_path(_wsum, sub)
             update = jax.lax.psum(update, CLIENT_AXIS)
             if stacked_prev:
                 return update, losses, locals_stacked
@@ -1397,31 +1268,16 @@ class ShardMapEngine(_BatchedEngineBase):
             return self._agg_fns[key]
         partition = self.partition
 
-        # Compressed rounds always reduce in the per-leaf tree form (the
-        # packed epilogue is the uncompressed fused path's fast lane).
-        if self.fused_adam and self.compression is None:
-            def splice(global_params, updates):
-                # Scatter the summed transmitted rows into the packed global
-                # and unpack — ``unpack`` restores each leaf's recorded
-                # dtype, so untransmitted f32 leaves round-trip bit-exact.
-                self.trace_count += 1
-                summed = jax.tree.map(lambda *xs: sum(xs), *updates)
-                pg, meta = madam_ops.pack(global_params, FUSED_BLOCK_ROWS)
-                sel = tuple(range(partition.num_groups)) if group < 0 else group
-                tx = _transmitted_rows(global_params, partition, sel)
-                pg = pg.at[tx].set(summed)
-                return madam_ops.unpack(pg, meta)
-        else:
-            def splice(global_params, updates):
-                self.trace_count += 1
-                summed = jax.tree.map(lambda *xs: sum(xs), *updates)
-                ref = (
-                    global_params if group < 0
-                    else masking.select(global_params, partition, group)
-                )
-                ref = aggregation.drop_local_stats(ref)
-                averaged = jax.tree.map(lambda s, r: s.astype(r.dtype), summed, ref)
-                return masking.tree_update(global_params, averaged)
+        def splice(global_params, updates):
+            self.trace_count += 1
+            summed = jax.tree.map(lambda *xs: sum(xs), *updates)
+            ref = (
+                global_params if group < 0
+                else masking.select(global_params, partition, group)
+            )
+            ref = aggregation.drop_local_stats(ref)
+            averaged = jax.tree.map(lambda s, r: s.astype(r.dtype), summed, ref)
+            return masking.tree_update(global_params, averaged)
 
         self._agg_fns[key] = jax.jit(splice, donate_argnums=self._donate_params())
         return self._agg_fns[key]
@@ -1437,31 +1293,18 @@ class ShardMapEngine(_BatchedEngineBase):
             return self._agg_fns[key]
         partition = self.partition
 
-        if self.fused_adam and self.compression is None:
-            def splice(global_params, updates, trained):
-                # Row-granular zero-trainer freeze: a row whose group nobody
-                # trained keeps the packed global's value bit-exact, exactly
-                # like the unfused leaf-granular ``jnp.where(trained[g], ...)``.
-                self.trace_count += 1
-                summed = jax.tree.map(lambda *xs: sum(xs), *updates)
-                pg, meta = madam_ops.pack(global_params, FUSED_BLOCK_ROWS)
-                rows, gids_rows = _plan_rows(global_params, partition)
-                keep = trained[jnp.asarray(gids_rows)][:, None]
-                pg = pg.at[rows].set(jnp.where(keep, summed, pg[rows]))
-                return madam_ops.unpack(pg, meta)
-        else:
-            def splice(global_params, updates, trained):
-                self.trace_count += 1
-                summed = jax.tree.map(lambda *xs: sum(xs), *updates)
-                ref = aggregation.drop_local_stats(global_params)
+        def splice(global_params, updates, trained):
+            self.trace_count += 1
+            summed = jax.tree.map(lambda *xs: sum(xs), *updates)
+            ref = aggregation.drop_local_stats(global_params)
 
-                def _choose(path, s, r):
-                    g = partition.group_of(
-                        "/".join(masking._entry_str(e) for e in path))
-                    return jnp.where(trained[g], s.astype(r.dtype), r)
+            def _choose(path, s, r):
+                g = partition.group_of(
+                    "/".join(masking._entry_str(e) for e in path))
+                return jnp.where(trained[g], s.astype(r.dtype), r)
 
-                averaged = jax.tree_util.tree_map_with_path(_choose, summed, ref)
-                return masking.tree_update(global_params, averaged)
+            averaged = jax.tree_util.tree_map_with_path(_choose, summed, ref)
+            return masking.tree_update(global_params, averaged)
 
         self._agg_fns[key] = jax.jit(splice, donate_argnums=self._donate_params())
         return self._agg_fns[key]
@@ -1566,7 +1409,6 @@ def make_engine(
     algo: AlgoConfig,
     sim_devices: int = 0,
     donate: bool = True,
-    fused_adam: bool = False,
     compression: compress.CompressionConfig | None = None,
     state_store: Any = None,
 ):
@@ -1586,10 +1428,9 @@ def make_engine(
     into the next round (``run_federated`` does; pass ``donate=False`` to
     keep re-feeding the same tree, e.g. for fixed-workload benchmarking).
 
-    ``fused_adam`` routes every local step through the Pallas masked-Adam
-    kernel (interpret mode off-TPU — docs/KERNELS.md): packed (rows, 128)
-    optimizer state, block-masked fused update, and on the shard_map engine
-    a packed weight-scale epilogue feeding the on-mesh psum.
+    The local step's update rule is the trainer's (``LocalTrainer.fused``:
+    tree Adam, or the Pallas masked-Adam kernel — docs/KERNELS.md); every
+    engine aggregates the same per-leaf tree either way.
 
     ``compression`` (a ``core.compress.CompressionConfig``, or ``None`` for
     the byte-identical legacy paths) compresses every client's transmitted
@@ -1599,15 +1440,14 @@ def make_engine(
     """
     if name == "sequential":
         return SequentialEngine(trainer=trainer, partition=partition, algo=algo,
-                                fused_adam=fused_adam, compression=compression,
+                                compression=compression,
                                 state_store=state_store)
     if name == "vmap":
         return VmapEngine(trainer=trainer, partition=partition, algo=algo,
-                          donate=donate, fused_adam=fused_adam,
-                          compression=compression, state_store=state_store)
+                          donate=donate, compression=compression,
+                          state_store=state_store)
     if name == "shard_map":
         return ShardMapEngine(trainer=trainer, partition=partition, algo=algo,
                               donate=donate, devices=sim_devices,
-                              fused_adam=fused_adam, compression=compression,
-                              state_store=state_store)
+                              compression=compression, state_store=state_store)
     raise ValueError(f"unknown engine {name!r}; expected one of {ENGINES}")

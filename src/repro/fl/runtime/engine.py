@@ -191,11 +191,12 @@ def run_federated_async(
         partition=partition,
         algo=run_cfg.algo,
         adam=AdamConfig(lr=run_cfg.lr, eps=run_cfg.adam_eps),
+        fused=run_cfg.fused_adam,
     )
     engine = make_engine(
         run_cfg.engine, trainer=trainer, partition=partition,
         algo=run_cfg.algo, sim_devices=run_cfg.sim_devices,
-        donate=run_cfg.donate_buffers, fused_adam=run_cfg.fused_adam,
+        donate=run_cfg.donate_buffers,
     )
     policy = make_policy(
         run_cfg.async_policy, partition,

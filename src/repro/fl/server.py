@@ -239,6 +239,7 @@ def run_federated(
         partition=partition,
         algo=run_cfg.algo,
         adam=AdamConfig(lr=run_cfg.lr, eps=run_cfg.adam_eps),
+        fused=run_cfg.fused_adam,
     )
     ccfg = compress.make_config(
         run_cfg.compression, topk_fraction=run_cfg.topk_fraction,
@@ -248,7 +249,7 @@ def run_federated(
     engine = make_engine(
         run_cfg.engine, trainer=trainer, partition=partition,
         algo=run_cfg.algo, sim_devices=run_cfg.sim_devices,
-        donate=run_cfg.donate_buffers, fused_adam=run_cfg.fused_adam,
+        donate=run_cfg.donate_buffers,
         compression=ccfg, state_store=state_store,
     )
     assigner = PlanAssigner(

@@ -23,7 +23,7 @@ axis, and a (clients, 8, 128) array still tiles where a (clients, 4) SMEM
 array would not.
 
 NOTE: on a homogeneous FedPart partial round the engines' fused step packs
-only the trained group (``fl.client.LocalTrainer.make_fused_step``): the
+only the trained group (``fl.client.LocalTrainer.make_step``): the
 kernel streams those rows with every block trained, and frozen tensors never
 reach it.  The per-block mask serves the whole-tree masked form, which FNU
 rounds and per-client layer plans run, and any mixed-group tensor boundary.

@@ -15,9 +15,9 @@ records every leaf's original dtype and ``unpack`` restores it, so
 ``unpack(pack(tree))`` round-trips mixed-dtype trees exactly
 (f32 -> f32 and bf16 -> f32 -> bf16 are value-exact).
 
-``pack_stacked``/``unpack_stacked`` are the client-stacked variants the
-batched engines use: trees whose every leaf carries a leading ``clients``
-axis pack to ``(clients, R, 128)`` with the same per-client layout.
+``pack_stacked``/``unpack_stacked`` are the client-stacked variants: trees
+whose every leaf carries a leading ``clients`` axis pack to ``(clients, R,
+128)`` with the same per-client layout.
 """
 
 from __future__ import annotations

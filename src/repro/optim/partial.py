@@ -1,6 +1,12 @@
 """Partial (FedPart) optimizer: gradients and optimizer state exist only for
 the round's trainable group.
 
+The three steps here are independent references: the kernel and
+equivalence tests compare the hot path against them.  The hot path itself
+is ``fl.client.LocalTrainer`` (one step builder, ``make_step``, whose update
+rule is tree Adam or the fused kernel); nothing in the framework calls
+these steps.
+
 Two mathematically equivalent realisations (asserted equal in
 ``tests/test_partial_equivalence.py``):
 
@@ -8,20 +14,20 @@ Two mathematically equivalent realisations (asserted equal in
   the binary mask S.  Reference semantics; wasteful.
 * ``partitioned_step`` — gradients w.r.t. the pruned trainable subtree only,
   frozen remainder closed over as constants.  XLA prunes the dead backward
-  graph; Adam m/v are allocated for the subtree only.  This is what the
-  framework runs.
+  graph; Adam m/v are allocated for the subtree only.  A homogeneous
+  partial round of ``LocalTrainer`` takes this form.
 
 A third realisation, ``fused_masked_step``, is Eq. 1 through the Pallas
 masked-Adam kernel (``kernels/masked_adam``): params/grads are packed into
 the kernel's (rows, 128) block layout, the whole optimizer update runs as one
 fused pass with a per-block mask, and m/v live *packed* across steps
 (``fused_adam_init``).  The three-way equivalence is pinned in
-``tests/test_kernels_adam.py``.  The engines' ``fused_adam=True`` path
-(``fl.client.LocalTrainer.make_fused_step``, docs/KERNELS.md) keeps this
-whole-tree masked form for FNU rounds and per-client plans; a homogeneous
-partial round joins the two forms instead: it packs, differentiates and
-carries only the trained group, as ``partitioned_step`` does, and runs the
-kernel over that group's rows with m/v packed for them alone.
+``tests/test_kernels_adam.py``.  ``LocalTrainer(fused=True)``
+(docs/KERNELS.md) keeps this whole-tree masked form for FNU rounds and
+per-client plans; a homogeneous partial round joins the two forms instead:
+it packs, differentiates and carries only the trained group, as
+``partitioned_step`` does, and runs the kernel over that group's rows with
+m/v packed for them alone.
 """
 
 from __future__ import annotations

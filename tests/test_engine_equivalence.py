@@ -312,18 +312,16 @@ def test_fused_ragged_small_client_bucket():
 
 def test_fused_rejects_weight_decay(setup):
     """The kernel implements plain Adam; a weight-decay config must be
-    refused at engine construction, not silently ignored."""
-    from repro.fl import LocalTrainer, make_engine
+    refused at trainer construction, not silently ignored."""
+    from repro.fl import LocalTrainer
     from repro.optim.adam import AdamConfig
 
     adapter, _, _ = setup
     params = adapter.init(jax.random.key(0))
     part = adapter.partition(params)
-    trainer = LocalTrainer(adapter=adapter, partition=part,
-                           algo=AlgoConfig(), adam=AdamConfig(weight_decay=0.1))
     with pytest.raises(ValueError, match="weight_decay"):
-        make_engine("vmap", trainer=trainer, partition=part,
-                    algo=AlgoConfig(), fused_adam=True)
+        LocalTrainer(adapter=adapter, partition=part, algo=AlgoConfig(),
+                     adam=AdamConfig(weight_decay=0.1), fused=True)
 
 
 def test_homogeneous_plan_is_identical_to_default(setup):

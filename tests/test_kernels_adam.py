@@ -439,12 +439,14 @@ def resnet4():
     adapter = resnet_task("resnet4", num_classes=4)
     params = adapter.init(jax.random.key(0))
     part = adapter.partition(params)
-    trainer = LocalTrainer(adapter=adapter, partition=part, algo=AlgoConfig(),
-                           adam=_RN_ADAM)
+    trainers = {fused: LocalTrainer(adapter=adapter, partition=part,
+                                    algo=AlgoConfig(), adam=_RN_ADAM,
+                                    fused=fused)
+                for fused in (False, True)}
     ks = jax.random.split(jax.random.key(3), 2)
     xs = jax.random.normal(ks[0], (_RN_STEPS, 6, 8, 8, 3), jnp.float32)
     ys = jax.random.randint(ks[1], (_RN_STEPS, 6), 0, 4)
-    return adapter, params, part, trainer, xs, ys
+    return adapter, params, part, trainers, xs, ys
 
 
 def _leaf_paths(tree):
@@ -473,19 +475,33 @@ def _reference_run(adapter, params, part, group, xs, ys, fused):
     return p
 
 
-@pytest.mark.parametrize("group", [0, 3, 5], ids=["stem_conv", "shortcut_bns",
-                                                  "head"])
+_SUBTREE_CASES = [
+    pytest.param(group, fused, id=name if fused else f"{name}-tree_adam")
+    for fused in (True, False)
+    for group, name in ((0, "stem_conv"), (3, "shortcut_bns"), (5, "head"))
+]
+
+
+@pytest.mark.parametrize("group,fused", _SUBTREE_CASES)
 def test_subtree_fused_step_matches_partitioned_and_masked_fused(resnet4,
-                                                                 group):
-    """Over several steps, the fused step on the trained group's subtree
-    (``LocalTrainer.make_fused_step(group)``) equals the unfused pruned step
-    and the whole-tree masked fused step: trained leaves close, frozen leaves
-    bit-equal to the start, every layer's running moments refreshed."""
-    adapter, params, part, trainer, xs, ys = resnet4
-    carry, opt = trainer.fused_init(params, group)
-    assert opt.m.shape[0] == trainer.fused_kernel_rows(params, group) \
-        < ops.packed_rows(params)
-    step = jax.jit(trainer.make_fused_step(group))
+                                                                 group, fused):
+    """Over several steps, the step on the trained group's subtree
+    (``LocalTrainer.make_step(group)``), with the fused kernel update or
+    tree Adam, equals the unfused pruned step and the whole-tree masked
+    fused step: trained leaves close, frozen leaves bit-equal to the start,
+    every layer's running moments refreshed.  Its Adam state covers the
+    trained leaves alone."""
+    adapter, params, part, trainers, xs, ys = resnet4
+    trainer = trainers[fused]
+    carry, opt = trainer.init(params, group)
+    if fused:
+        assert opt.m.shape[0] == trainer.kernel_rows(params, group) \
+            < ops.packed_rows(params)
+    else:
+        paths = [path for path, _ in _leaf_paths(opt.m)]
+        assert paths and all(part.group_of(path) == group
+                             and not is_local_stat(path) for path in paths)
+    step = jax.jit(trainer.make_step(group))
     for x, y in zip(xs, ys):
         carry, opt, _ = step(carry, opt, x, y, params, params)
     assert int(opt.step) == _RN_STEPS
@@ -511,17 +527,17 @@ def test_subtree_fused_round_discards_padded_steps(resnet4):
     every layer's BN running moments move."""
     from repro.fl import AlgoConfig, make_engine
 
-    adapter, params, part, trainer, xs, ys = resnet4
-    group = 3
+    adapter, params, part, trainers, xs, ys = resnet4
+    group, trainer = 3, trainers[True]
     engine = make_engine("vmap", trainer=trainer, partition=part,
-                         algo=AlgoConfig(), fused_adam=True)
+                         algo=AlgoConfig())
     valid = jnp.asarray([[1, 1, 0], [1, 1, 1]], jnp.float32)
     stacked, (losses, _) = engine._local_fn(group, False)(
         params, jnp.stack([xs, xs]), jnp.stack([ys, ys]), valid, params)
 
-    step = jax.jit(trainer.make_fused_step(group))
+    step = jax.jit(trainer.make_step(group))
     for c, n in enumerate((2, 3)):
-        carry, opt = trainer.fused_init(params, group)
+        carry, opt = trainer.init(params, group)
         for x, y in zip(xs[:n], ys[:n]):
             carry, opt, _ = step(carry, opt, x, y, params, params)
         want = masking.tree_update(params, carry)

@@ -159,9 +159,9 @@ def test_fused_partial_round_streams_only_the_trained_group(one_chip,
     shapes = jax.eval_shape(adapter.init, jax.random.key(0))
     part = adapter.partition(shapes)
     trainer = LocalTrainer(adapter=adapter, partition=part, algo=AlgoConfig(),
-                           adam=AdamConfig())
+                           adam=AdamConfig(), fused=True)
     engine = make_engine("vmap", trainer=trainer, partition=part,
-                         algo=AlgoConfig(), fused_adam=True)
+                         algo=AlgoConfig())
 
     def s(shape, dt=jnp.float32):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
@@ -173,7 +173,7 @@ def test_fused_partial_round_streams_only_the_trained_group(one_chip,
     partial = _compile(engine._local_fn(14, False), *args)
     full = _compile(engine._local_fn(-1, False), *args)
 
-    trained = trainer.fused_kernel_rows(shapes, 14, BLOCK_ROWS)
+    trained = trainer.kernel_rows(shapes, 14)
     assert trained == 18_448                 # 18,432 + 8 + 8 rows
     assert _kernel_p_rows(partial.as_text()) == trained
     assert _kernel_p_rows(full.as_text()) == resnet18_rows
